@@ -50,5 +50,7 @@ def main(full=False):
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     import sys
     main(full="--full" in sys.argv)

@@ -601,4 +601,6 @@ def write_bench_json(qrd4, qrd8, solve, speedup_8x8, rot_per_s,
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     main()

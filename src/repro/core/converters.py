@@ -27,9 +27,18 @@ _I64 = lambda v: jnp.asarray(v, jnp.int64)
 
 
 def ilog2(a):
-    """floor(log2(a)) for int64 a > 0 (exact for a < 2^53)."""
-    _, e = jnp.frexp(a.astype(jnp.float64))
-    return (e - 1).astype(jnp.int64)
+    """floor(log2(a)) for int64 a > 0, by an exact integer binary search.
+
+    (A float64 ``frexp`` detour is exact only below 2^53, and its int64
+    bitcast does not compile for the TPU.)
+    """
+    a = jnp.asarray(a, jnp.int64)
+    r = jnp.zeros_like(a)
+    for s in (32, 16, 8, 4, 2, 1):
+        big = (a >> s) > 0
+        a = jnp.where(big, a >> s, a)
+        r = r + jnp.where(big, s, 0)
+    return r
 
 
 def _rshift_rne(v, sh):

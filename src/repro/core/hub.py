@@ -15,6 +15,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from .formats import frexp, ldexp
+
 __all__ = ["hub_quantize", "hub_error_bound"]
 
 
@@ -27,11 +29,11 @@ def hub_quantize(x, man_bits: int):
     sign = jnp.sign(xd)
     ax = jnp.abs(xd)
     is_zero = ax == 0.0
-    f, e = jnp.frexp(jnp.where(is_zero, 1.0, ax))  # f in [0.5, 1)
+    f, e = frexp(jnp.where(is_zero, 1.0, ax))  # f in [0.5, 1)
     scale = jnp.float64(1 << (man_bits + 1))
     # truncate to man_bits fractional bits of the [1,2) significand, + ILSB
     sig = (jnp.floor(f * scale) + 0.5) / scale     # in [0.5, 1)
-    out = sign * jnp.ldexp(sig, e)
+    out = sign * ldexp(sig, e)
     out = jnp.where(is_zero, 0.0, out)
     return out.astype(jnp.result_type(x))
 

@@ -461,7 +461,7 @@ def qr_cordic_wavefront(A, unit: GivensUnit, compute_q=True, stages=None,
     all rotations of a stage — their row pairs are disjoint by construction
     — run in one shot along a (TILE_B, Pmax, e) pair axis, so the
     sequential depth collapses from ``len(steps)`` dependent rotations to
-    ``len(stages)`` scan iterations, and the trace holds one stage body
+    ``len(stages)`` loop iterations, and the trace holds one stage body
     instead of the whole unrolled schedule.  (Q, R) are bit-identical to
     `qr_cordic` on the flattened stage schedule (same `GivensUnit`
     arithmetic; within-stage rotations commute).

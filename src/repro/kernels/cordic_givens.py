@@ -24,6 +24,7 @@ Half-Unit-Biased semantics (negate-by-inversion + the Fig. 6 carry-in rule).
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -34,12 +35,27 @@ from jax.experimental import pallas as pl
 from repro.core.cordic import GAIN_TABLE
 
 __all__ = ["vectoring_call", "rotation_call", "fused_call",
-           "fused_rotate_block", "fused_rotate_pairs", "fused_rotate_ctrl",
-           "fused_replay", "comp_q30",
-           "packed_to_lanes", "lanes_to_packed", "TILE_B", "TILE_L"]
+           "fused_rotate_block", "fused_rotate_pairs", "fused_replay",
+           "comp_q30", "x32_trace", "packed_to_lanes", "lanes_to_packed",
+           "TILE_B", "TILE_L"]
 
 TILE_B = 8     # sublane tile (int32 native tile is (8, 128))
 TILE_L = 128   # lane tile
+
+
+def x32_trace(enabled: bool = True):
+    """Context for tracing a compiled kernel: x64 off inside it.
+
+    The package turns x64 on globally (the host-side packed words are
+    int64), so every Python int in an index map or a kernel body would
+    trace as an int64 scalar — and Mosaic refuses 64-bit values ("failed
+    to legalize operation 'func.return'", "64-bit types are not
+    supported").  The 32-bit datapaths build their `pallas_call` inside
+    this context, where those scalars are int32; their operands are
+    32-bit by construction, so the arithmetic is unchanged.  The int64
+    datapaths (interpret mode only) pass ``enabled=False``.
+    """
+    return jax.enable_x64(False) if enabled else contextlib.nullcontext()
 
 
 def packed_to_lanes(p):
@@ -69,19 +85,20 @@ def comp_q30(iters: int) -> int:
 
 
 def _gain_mul_q30(v, comp: int):
-    """(v * comp) >> 30 with all partial products inside int32.
+    """(v * comp) >> 30, rounded to nearest, all partial products in int32.
 
-    v: w-bit int32 (|v| < 2^29); comp: Q30 constant < 2^30.
-    Split both into 15-bit halves; truncating partial shifts lose < 1 LSB.
+    v: w-bit int32 (|v| < 2^29); comp: Q30 constant < 2^30.  Split both
+    into 15-bit halves; the three low partial products are summed before
+    one rounding shift (each < 2^30, the sum < 2^31).  Rounding, not
+    truncation: a truncating shift biases every gain compensation toward
+    -inf, and the bias accumulates over the m rotations of a column.
     """
     c_hi = comp >> 15
     c_lo = comp & 0x7FFF
     v_hi = v >> 15          # arithmetic: keeps the sign
     v_lo = v & 0x7FFF
-    return (v_hi * c_hi
-            + ((v_hi * c_lo) >> 15)
-            + ((v_lo * c_hi) >> 15)
-            + ((v_lo * c_lo) >> 30))
+    mid = v_hi * c_lo + v_lo * c_hi + ((v_lo * c_lo) >> 15) + (1 << 14)
+    return v_hi * c_hi + (mid >> 15)
 
 
 def _microrotation(x, y, i: int, d_pos, hub: bool):
@@ -115,16 +132,10 @@ def _vectoring_kernel(x_ref, y_ref, xo_ref, yo_ref, flip_ref, sig_ref,
                       *, iters: int, hub: bool, comp: int):
     x = x_ref[...]
     y = y_ref[...]
-    flip = (x < 0)
-    x = jnp.where(flip, _negate(x, hub), x)
-    y = jnp.where(flip, _negate(y, hub), y)
-    sig = jnp.zeros_like(x)
-    for i in range(iters):          # static unroll == the FPGA pipeline depth
-        d_pos = y < 0
-        x, y = _microrotation(x, y, i, d_pos, hub)
-        sig = sig | (d_pos.astype(jnp.int32) << i)
-    xo_ref[...] = _gain_mul_q30(x, comp)
-    yo_ref[...] = _gain_mul_q30(y, comp)
+    flip, sig = _vector_lead(x, y, iters=iters, hub=hub)
+    # the leading pair's replay by its own sigma IS the vectoring result
+    xo_ref[...], yo_ref[...] = fused_replay(x, y, flip, sig, iters=iters,
+                                            hub=hub, comp=comp)
     flip_ref[...] = flip.astype(jnp.int32)
     sig_ref[...] = sig
 
@@ -141,13 +152,14 @@ def vectoring_call(x, y, *, iters: int, hub: bool, interpret: bool = True):
     out_shape = [jax.ShapeDtypeStruct((B, 1), jnp.int32)] * 4
     kernel = functools.partial(_vectoring_kernel, iters=iters, hub=hub,
                                comp=comp_q30(iters))
-    return pl.pallas_call(
-        kernel, grid=grid,
-        in_specs=[spec, spec],
-        out_specs=[spec, spec, spec, spec],
-        out_shape=out_shape,
-        interpret=interpret,
-    )(x, y)
+    with x32_trace():
+        return pl.pallas_call(
+            kernel, grid=grid,
+            in_specs=[spec, spec],
+            out_specs=[spec, spec, spec, spec],
+            out_shape=out_shape,
+            interpret=interpret,
+        )(x, y)
 
 
 # ---------------------------------------------------------------------------
@@ -155,17 +167,10 @@ def vectoring_call(x, y, *, iters: int, hub: bool, interpret: bool = True):
 # ---------------------------------------------------------------------------
 def _rotation_kernel(flip_ref, sig_ref, x_ref, y_ref, xo_ref, yo_ref,
                      *, iters: int, hub: bool, comp: int):
-    x = x_ref[...]
-    y = y_ref[...]
-    flip = flip_ref[...] != 0           # (TB, 1) -> broadcasts over lanes
-    sig = sig_ref[...]
-    x = jnp.where(flip, _negate(x, hub), x)
-    y = jnp.where(flip, _negate(y, hub), y)
-    for i in range(iters):
-        d_pos = ((sig >> i) & 1) == 1   # (TB, 1) control word, lane-broadcast
-        x, y = _microrotation(x, y, i, d_pos, hub)
-    xo_ref[...] = _gain_mul_q30(x, comp)
-    yo_ref[...] = _gain_mul_q30(y, comp)
+    # (TB, 1) control words broadcast across the lane axis
+    xo_ref[...], yo_ref[...] = fused_replay(
+        x_ref[...], y_ref[...], flip_ref[...], sig_ref[...], iters=iters,
+        hub=hub, comp=comp)
 
 
 def rotation_call(x, y, flip, sigma, *, iters: int, hub: bool,
@@ -179,13 +184,14 @@ def rotation_call(x, y, flip, sigma, *, iters: int, hub: bool,
     out_shape = [jax.ShapeDtypeStruct((B, L), jnp.int32)] * 2
     kernel = functools.partial(_rotation_kernel, iters=iters, hub=hub,
                                comp=comp_q30(iters))
-    return pl.pallas_call(
-        kernel, grid=grid,
-        in_specs=[ctrl, ctrl, tile, tile],
-        out_specs=[tile, tile],
-        out_shape=out_shape,
-        interpret=interpret,
-    )(flip, sigma, x, y)
+    with x32_trace():
+        return pl.pallas_call(
+            kernel, grid=grid,
+            in_specs=[ctrl, ctrl, tile, tile],
+            out_specs=[tile, tile],
+            out_shape=out_shape,
+            interpret=interpret,
+        )(flip, sigma, x, y)
 
 
 # ---------------------------------------------------------------------------
@@ -195,17 +201,12 @@ def rotation_call(x, y, flip, sigma, *, iters: int, hub: bool,
 # the leading column and replayed over the whole block before a single
 # write-back.  HBM traffic per element drops 2x (one read + one write).
 # ---------------------------------------------------------------------------
-def fused_rotate_block(x, y, *, iters: int, hub: bool, comp: int):
-    """Fused Givens step on two resident (TB, L) row blocks.
+def _vector_lead(xl, yl, *, iters: int, hub: bool):
+    """Vectoring recurrence on the leading pairs -> (flip, sigma).
 
-    Vectoring on the leading column derives the control words (flip +
-    sigma), then the whole block — leading column included; its replay by
-    its own sigma IS the vectoring result — rotates with the broadcast
-    words and is gain-compensated.  Shared by the fused row kernel and the
-    blocked QR kernel (`qrd_blocked`).
+    ``flip`` is the boolean coarse π-flip, ``sigma`` the int32 packed
+    direction bits (bit i == 1 ⇔ d_i = +1), both shaped like ``xl``.
     """
-    xl = x[:, :1]
-    yl = y[:, :1]
     flip = xl < 0
     xl = jnp.where(flip, _negate(xl, hub), xl)
     yl = jnp.where(flip, _negate(yl, hub), yl)
@@ -214,26 +215,52 @@ def fused_rotate_block(x, y, *, iters: int, hub: bool, comp: int):
         d_pos = yl < 0
         xl, yl = _microrotation(xl, yl, i, d_pos, hub)
         sig = sig | (d_pos.astype(jnp.int32) << i)
-    x = jnp.where(flip, _negate(x, hub), x)
-    y = jnp.where(flip, _negate(y, hub), y)
+    return flip, sig
+
+
+def fused_replay(x, y, flip, sig, *, iters: int, hub: bool, comp: int):
+    """Replay `(flip, sigma)` control words over two row blocks.
+
+    x, y : int32 rows; flip (bool or 0/1 int32) and sig (int32) are
+    broadcastable against them — a trailing singleton element axis
+    broadcasts one control word across its row.  Replaying sigma on the
+    pair that produced it reproduces the vectoring output bit for bit,
+    and on any other column applies the exact same micro-rotation
+    sequence — the trailing-panel update is therefore bit-identical to
+    having rotated the full-width rows in one shot.
+    """
+    fb = flip != jnp.zeros_like(flip)     # no weak literal: bool or 0/1
+    x = jnp.where(fb, _negate(x, hub), x)
+    y = jnp.where(fb, _negate(y, hub), y)
     for i in range(iters):
         d_pos = ((sig >> i) & 1) == 1
         x, y = _microrotation(x, y, i, d_pos, hub)
     return _gain_mul_q30(x, comp), _gain_mul_q30(y, comp)
 
 
+def fused_rotate_block(x, y, *, iters: int, hub: bool, comp: int):
+    """Fused Givens step on two resident (TB, L) row blocks.
+
+    Vectoring on the leading column derives the control words (flip +
+    sigma), then the whole block — leading column included; its replay by
+    its own sigma IS the vectoring result — rotates with the broadcast
+    words and is gain-compensated.
+    """
+    flip, sig = _vector_lead(x[:, :1], y[:, :1], iters=iters, hub=hub)
+    return fused_replay(x, y, flip, sig, iters=iters, hub=hub, comp=comp)
+
+
 def fused_rotate_pairs(x, y, lead, *, iters: int, hub: bool, comp: int):
     """Fused Givens step on a whole *pair axis* of resident row blocks.
 
-    The wavefront variant of `fused_rotate_block` (DESIGN.md §8): instead
-    of one (TB, L) row pair with its leading element at lane 0, the inputs
-    carry a full Sameh–Kuck stage — ``x``/``y`` are (TB, P, e) pivot/target
-    rows at *uniform* width e, and ``lead`` is the (P, e) 0/1 one-hot of
-    each pair's leading column (the annihilated entry's column).  The
-    leading pair is extracted by the one-hot contraction, vectoring derives
-    one (flip, sigma) control word per (batch, pair) lane, and the replay
-    broadcasts it across the whole e axis — every pair of the stage rotates
-    in one shot.
+    The kernel-resident building block of every blocked QR kernel
+    (DESIGN.md §8, §14): ``x``/``y`` are (P, TB, e) pivot/target rows at
+    *uniform* width e, and ``lead`` is the (P, 1, e) 0/1 one-hot of each
+    pair's leading column (the annihilated entry's column).  The leading
+    pair is extracted by the one-hot contraction, vectoring derives one
+    (flip, sigma) control word per (pair, batch) lane, and the replay
+    broadcasts it across the whole e axis — every pair rotates in one
+    shot.
 
     Column masking is the caller's job: lanes left of the leading column
     are rotated here too (uniform shape keeps the datapath wide) and must
@@ -244,81 +271,18 @@ def fused_rotate_pairs(x, y, lead, *, iters: int, hub: bool, comp: int):
     result bit for bit (same micro-rotation sequence), so each pair's lanes
     at and right of `lead` match `fused_rotate_block` on the ragged slice
     exactly.
+
+    Returns ``(rx, ry, flip, sig)`` — the rotated rows plus the (P, TB, 1)
+    int32 control words (flip as 0/1), which the panel kernels export for
+    replay over trailing panels (`fused_replay`).
     """
-    sel = lead[None].astype(x.dtype)                 # (1, P, e) 0/1
+    sel = lead.astype(x.dtype)
     # dtype-pinned sums: default integer accumulation widens to int64
-    xl = jnp.sum(x * sel, axis=-1, dtype=x.dtype)    # (TB, P) leading pair
-    yl = jnp.sum(y * sel, axis=-1, dtype=y.dtype)
-    flip = xl < 0
-    xl = jnp.where(flip, _negate(xl, hub), xl)
-    yl = jnp.where(flip, _negate(yl, hub), yl)
-    sig = jnp.zeros_like(xl)
-    for i in range(iters):
-        d_pos = yl < 0
-        xl, yl = _microrotation(xl, yl, i, d_pos, hub)
-        sig = sig | (d_pos.astype(jnp.int32) << i)
-    fb = flip[..., None]                             # (TB, P, 1) -> e lanes
-    x = jnp.where(fb, _negate(x, hub), x)
-    y = jnp.where(fb, _negate(y, hub), y)
-    for i in range(iters):
-        d_pos = ((sig[..., None] >> i) & 1) == 1
-        x, y = _microrotation(x, y, i, d_pos, hub)
-    return _gain_mul_q30(x, comp), _gain_mul_q30(y, comp)
-
-
-def fused_rotate_ctrl(x, y, lead, *, iters: int, hub: bool, comp: int):
-    """`fused_rotate_pairs` for one pair, exporting the control words.
-
-    The panel-factorization building block (DESIGN.md §14): identical
-    vectoring recurrence and replay as `fused_rotate_block`, but the
-    leading pair is selected by the ``lead`` one-hot (the panel kernels
-    rotate at uniform panel width, like the wavefront path) and the
-    derived ``(flip, sigma)`` words are *returned* so the caller can
-    replay the whole rotation set over trailing panels later
-    (`fused_replay`) — the paper's compute-once/replay-everywhere
-    control-word contract, extended across kernel launches.
-
-    x, y : (TB, pw) int32 pivot/target rows at uniform panel width.
-    lead : (1, pw) 0/1 one-hot of the leading (annihilated) column.
-
-    Returns ``(rx, ry, flip, sig)`` — rotated rows plus (TB,) int32
-    control words.  Lanes at and right of `lead` match
-    `fused_rotate_block` on the ragged slice exactly; left lanes must be
-    restored by the caller (wavefront convention).
-    """
-    sel = lead.astype(x.dtype)                       # (1, pw) 0/1
-    xl = jnp.sum(x * sel, axis=-1, dtype=x.dtype)    # (TB,) leading pair
-    yl = jnp.sum(y * sel, axis=-1, dtype=y.dtype)
-    flip = xl < 0
-    xl = jnp.where(flip, _negate(xl, hub), xl)
-    yl = jnp.where(flip, _negate(yl, hub), yl)
-    sig = jnp.zeros_like(xl)
-    for i in range(iters):
-        d_pos = yl < 0
-        xl, yl = _microrotation(xl, yl, i, d_pos, hub)
-        sig = sig | (d_pos.astype(jnp.int32) << i)
-    rx, ry = fused_replay(x, y, flip.astype(jnp.int32), sig,
-                          iters=iters, hub=hub, comp=comp)
+    xl = jnp.sum(x * sel, axis=-1, keepdims=True, dtype=x.dtype)
+    yl = jnp.sum(y * sel, axis=-1, keepdims=True, dtype=y.dtype)
+    flip, sig = _vector_lead(xl, yl, iters=iters, hub=hub)
+    rx, ry = fused_replay(x, y, flip, sig, iters=iters, hub=hub, comp=comp)
     return rx, ry, flip.astype(jnp.int32), sig
-
-
-def fused_replay(x, y, flip, sig, *, iters: int, hub: bool, comp: int):
-    """Replay exported `(flip, sigma)` control words over two row blocks.
-
-    x, y : (TB, L) int32 rows; flip, sig : (TB,) int32 control words from
-    `fused_rotate_ctrl` (flip as 0/1).  Replaying sigma on the pair that
-    produced it reproduces the vectoring output bit for bit, and on any
-    other column applies the exact same micro-rotation sequence — the
-    trailing-panel update is therefore bit-identical to having rotated
-    the full-width rows in one shot.
-    """
-    fb = (flip != 0)[..., None]                      # (TB, 1) -> L lanes
-    x = jnp.where(fb, _negate(x, hub), x)
-    y = jnp.where(fb, _negate(y, hub), y)
-    for i in range(iters):
-        d_pos = ((sig[..., None] >> i) & 1) == 1
-        x, y = _microrotation(x, y, i, d_pos, hub)
-    return _gain_mul_q30(x, comp), _gain_mul_q30(y, comp)
 
 
 def _fused_kernel(x_ref, y_ref, xo_ref, yo_ref,
@@ -336,10 +300,11 @@ def fused_call(x, y, *, iters: int, hub: bool, interpret: bool = True):
     out_shape = [jax.ShapeDtypeStruct((B, L), jnp.int32)] * 2
     kernel = functools.partial(_fused_kernel, iters=iters, hub=hub,
                                comp=comp_q30(iters))
-    return pl.pallas_call(
-        kernel, grid=grid,
-        in_specs=[tile, tile],
-        out_specs=[tile, tile],
-        out_shape=out_shape,
-        interpret=interpret,
-    )(x, y)
+    with x32_trace():
+        return pl.pallas_call(
+            kernel, grid=grid,
+            in_specs=[tile, tile],
+            out_specs=[tile, tile],
+            out_shape=out_shape,
+            interpret=interpret,
+        )(x, y)

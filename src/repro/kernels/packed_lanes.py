@@ -77,6 +77,19 @@ def lanes_stack(pair):
     return jnp.stack([h.astype(_I32), l.astype(_I32)], axis=-1)
 
 
+def _split(L):
+    """Stacked (..., 2) lane word -> (hi, lo) tuple, dtype kept."""
+    return L[..., 0], L[..., 1]
+
+
+def _u32_pair(pair):
+    return pair[0].astype(_U32), pair[1].astype(_U32)
+
+
+def _i32_pair(pair):
+    return pair[0].astype(_I32), pair[1].astype(_I32)
+
+
 def from_i32(x):
     """Sign-extend an int32 array (small field values) to a lane pair."""
     x = jnp.asarray(x, _I32)
@@ -436,7 +449,9 @@ def _vectoring(x, y, iters, hub: bool):
         return (*nx, *ny, *sig)
 
     z = jnp.zeros_like(x[0])
-    out = jax.lax.fori_loop(0, iters, body, (*x, *y, z, z))
+    # int32 loop bounds: under x64 a Python-int bound makes the counter
+    # int64, which Mosaic cannot lower
+    out = jax.lax.fori_loop(_I32(0), _I32(iters), body, (*x, *y, z, z))
     return ((out[0], out[1]), (out[2], out[3]), flip, (out[4], out[5]))
 
 
@@ -450,7 +465,7 @@ def _rotation(x, y, flip, sig, iters, hub: bool):
         nx, ny = _microrotation((xh, xl), (yh, yl), i, d_pos, hub)
         return (*nx, *ny)
 
-    out = jax.lax.fori_loop(0, iters, body, (*x, *y))
+    out = jax.lax.fori_loop(_I32(0), _I32(iters), body, (*x, *y))
     return (out[0], out[1]), (out[2], out[3])
 
 
@@ -493,29 +508,45 @@ class LaneUnit:
         config.validate()
         self.cfg = config
 
-    def vector(self, xp, yp):
+    def vector_lanes(self, x, y):
+        """`vector` on unstacked words: ``(hi, lo)`` int32 tuples in and out.
+
+        The kernel-side entry point — no trailing lane axis, which Mosaic
+        would lay out on the 128-wide vector lane dimension.  Returns
+        ``(xr, yr, (flip, sig))`` with ``sig`` a ``(hi, lo)`` uint32 pair.
+        """
         cfg = self.cfg
         N = cfg.n
         iters = cfg.resolved_iters()
-        x, y = lanes_unstack(xp), lanes_unstack(yp)
-        xf, yf, m_exp = _input_convert(x, y, cfg, N)
+        xf, yf, m_exp = _input_convert(_u32_pair(x), _u32_pair(y), cfg, N)
         xr, yr, flip, sig = _vectoring(xf, yf, iters, cfg.hub)
         xr, yr = _apply_gain(xr, yr, iters, N + 2, cfg.hub)
-        return (lanes_stack(_output_convert(xr, m_exp, cfg, N)),
-                lanes_stack(_output_convert(yr, m_exp, cfg, N)),
-                (flip, lanes_stack(sig)))
+        return (_i32_pair(_output_convert(xr, m_exp, cfg, N)),
+                _i32_pair(_output_convert(yr, m_exp, cfg, N)),
+                (flip, sig))
 
-    def rotate(self, xp, yp, state):
+    def rotate_lanes(self, x, y, state):
+        """`rotate` on unstacked ``(hi, lo)`` int32 tuples; ``state`` as
+        returned by `vector_lanes`."""
         cfg = self.cfg
         N = cfg.n
         iters = cfg.resolved_iters()
         flip, sig = state
-        x, y = lanes_unstack(xp), lanes_unstack(yp)
-        xf, yf, m_exp = _input_convert(x, y, cfg, N)
-        xr, yr = _rotation(xf, yf, flip, lanes_unstack(sig), iters, cfg.hub)
+        xf, yf, m_exp = _input_convert(_u32_pair(x), _u32_pair(y), cfg, N)
+        xr, yr = _rotation(xf, yf, flip, sig, iters, cfg.hub)
         xr, yr = _apply_gain(xr, yr, iters, N + 2, cfg.hub)
-        return (lanes_stack(_output_convert(xr, m_exp, cfg, N)),
-                lanes_stack(_output_convert(yr, m_exp, cfg, N)))
+        return (_i32_pair(_output_convert(xr, m_exp, cfg, N)),
+                _i32_pair(_output_convert(yr, m_exp, cfg, N)))
+
+    def vector(self, xp, yp):
+        xr, yr, (flip, sig) = self.vector_lanes(_split(xp), _split(yp))
+        return (lanes_stack(xr), lanes_stack(yr), (flip, lanes_stack(sig)))
+
+    def rotate(self, xp, yp, state):
+        flip, sig = state
+        xr, yr = self.rotate_lanes(_split(xp), _split(yp),
+                                   (flip, lanes_unstack(sig)))
+        return lanes_stack(xr), lanes_stack(yr)
 
     def rotate_rows(self, row_x, row_y):
         """Rotate two stacked-lane rows (..., e, 2); vectoring on element 0."""

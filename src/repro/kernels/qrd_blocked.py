@@ -10,72 +10,66 @@ TPU: the whole (batched) m×e working tile is staged into VMEM once, every
 schedule step runs on the resident tile, and the result is written back
 once.
 
-Three datapaths, one schedule machinery:
+One schedule machinery (`qr_call`), four datapaths:
 
-`qr_packed_call` — bit-exact packed-word datapath (int64 lanes)
-    The tile holds *packed FP words* (int64, see `repro.core.formats`).
-    Each schedule step performs the unit's full per-step dataflow in
-    registers — input-convert (block-FP align), CORDIC vectoring on the
-    leading pair, sigma-replay rotation across the rows, gain compensation,
-    output-convert — by calling the same `GivensUnit` arithmetic as the
-    reference loop.  (Q, R) are therefore **bit-identical** to `qr_cordic`
-    for any `GivensConfig` (IEEE and HUB).  int64 lanes: interpret mode
-    only; it is the semantic reference for the two fast datapaths.
+`BlockFPPath` — int32 block-fixed-point significands (the TPU fast path)
+    Significands quantized once, outside the kernel, with one shared
+    exponent per (matrix, column) — Givens rotations only ever combine
+    same-column elements of two rows, so per-column block-FP scaling is
+    invariant under the whole schedule.  Arithmetic is the fused int32
+    pipeline of `cordic_givens` (w ≤ 30 bits, Q30 gain compensation).
 
-`qr_packed_lanes_call` — bit-exact packed-word datapath (dual int32 lanes)
-    The same packed words carried as (hi, lo) int32 lane pairs on a
-    trailing axis of size 2 (`cordic_givens.packed_to_lanes`), rotated by
-    the emulated-64-bit `LaneUnit` (`repro.kernels.packed_lanes`) — no
-    64-bit integer types anywhere in the kernel, so this datapath lowers
-    through Mosaic/Triton (DESIGN.md §11).  Bit-identical to
-    `qr_packed_call` by construction (asserted by tests).
+`LanePath` — bit-exact packed words on dual int32 lanes
+    The packed FP words (`repro.core.formats`) carried as separate hi/lo
+    int32 tiles and rotated by the emulated-64-bit `LaneUnit`
+    (`repro.kernels.packed_lanes`) — no 64-bit types in the kernel, so it
+    lowers through Mosaic.  Bit-identical to `PackedPath`.
 
-`qr_blockfp_call` — int32 block-fixed-point datapath (the TPU fast path)
-    The tile holds int32 significands quantized once, outside the kernel,
-    with one shared exponent per (matrix, column) — Givens rotations only
-    ever combine same-column elements of two rows, so per-column block-FP
-    scaling is invariant under the whole schedule.  Rows stay fixed-point
-    across *all* rotation steps: no per-step FP round-trips at all, a
-    single FP decode after the kernel returns.  Arithmetic is the fused
-    int32 pipeline of `cordic_givens` (w ≤ 30 bits, Q30 gain compensation),
-    so every intermediate fits the VPU's native int32 lanes — this path
-    runs ``interpret=False`` today wherever a Pallas compiler exists.
+`PackedPath` — bit-exact packed words on int64 lanes
+    The same `GivensUnit` arithmetic as the reference loop, so (Q, R) are
+    bit-identical to `qr_cordic`.  Interpret mode only: the semantic
+    reference of the two compiled datapaths.
 
-Two schedule machineries (one per sequential-depth regime):
+`ComplexPath` — three-rotation complex Givens on packed (re, im) pairs
+    (DESIGN.md §10), int64 lanes with a trailing axis of size 2.
+    Interpret mode only.
 
-step-serial (`qr_packed_call` / `qr_packed_lanes_call` / `qr_blockfp_call`)
-    Schedules are static tuples of `(pivot_row, target_row, col)` triples
-    (column-major `givens_schedule` or a flattened Sameh–Kuck pairing from
-    `repro.core.qrd`), unrolled at trace time — the kernel body is a
-    straight line of micro-rotation recurrences, exactly like the FPGA
-    pipeline.  Depth: one dependent rotation per step.
+Schedule machinery.  Every schedule enters as (S, Pmax) stage tables held
+in SMEM: pivot rows, target rows, leading columns, one row per stage.  A
+`lax.fori_loop` walks the stages; each iteration reads its pairs' rows by
+dynamic index, rotates all pairs of the stage at uniform width e in one
+shot (the one-hot ``lead`` mask selects each pair's leading column, the
+``active`` mask restores the lanes left of it), and stores the rows back.
+The step-serial column-major order is the special case of one pair per
+stage; the Sameh–Kuck wavefront (DESIGN.md §8) packs the disjoint pairs of
+each stage along a pair axis, so sequential depth is the number of stages.
+Padded pairs carry the out-of-range row ``m``: they read a clamped row and
+store nothing.  The trace holds one stage body whatever the schedule
+length.  ``table_layout`` selects how the tables travel: ``'split'``
+(three operands) or ``'stacked'`` (one) — an autotuner dimension
+(`repro.kernels.autotune`).
 
-wavefront (`qr_*_wavefront_call`, §8)
-    The Sameh–Kuck schedule enters as (S, Pmax) stage index tables
-    consumed by `lax.scan`: each iteration gathers ALL row pairs of one
-    stage into two (TILE_B, Pmax, e) tensors, rotates the whole pair axis
-    in one shot (per-pair column masks replace the ragged `[col:]`
-    slices), and scatters the rows back.  Depth: one scan iteration per
-    stage — min(m+n−2, 2m−3) instead of ~m·n/2 — and the trace holds one
-    stage body instead of the unrolled schedule.  ``table_layout``
-    selects how the three tables travel to the kernel: ``'split'`` (three
-    (S, Pmax) operands) or ``'stacked'`` (one (3S, Pmax) operand, a
-    single block transfer) — an autotuner search dimension
-    (`repro.kernels.autotune`).
+Tiled panel QR (DESIGN.md §14) reuses the machinery: ``qr_call(...,
+export=True)`` factors one panel while exporting each step's (flip, sigma)
+control words, and `replay_call` replays them over the trailing panels on
+a (batch, panel) grid — the paper's compute-once/replay-everywhere
+contract extended across kernel launches.  With the column-major schedule
+every rotation is elementwise in the column axis once its control word is
+fixed, so the split cannot change a single bit.
 
-Batch handling: every ``*_call`` wrapper pads the leading batch axis up to
-a multiple of ``tile_b`` with all-zero matrices (harmless through every
-datapath — vectoring on packed/block-FP zeros is exact) and slices the
-result back, so ragged batches are first-class here, not just in `ops.py`.
+Layout.  Kernel operands are rows-first, ``(m, B, e)``: a dynamic row index
+then addresses the leading (untiled) axis and one row of a batch tile is a
+dense (TB, e) block.  Callers hand in rows-first arrays
+(`repro.kernels.ops` transposes once around the call).  The batch axis is
+padded up to a multiple of ``tile_b`` with all-zero matrices (exact
+through every datapath) and sliced back.
 
-VMEM budget (DESIGN.md §5, §8): one (tile_b, m, e) tile per operand/result
-— int64 packed: 2·tb·m·e·8 bytes; dual-lane packed: the same bytes as
-int32 (tile_b, m, e, 2); int32 block-FP: 2·tb·m·e·4 bytes.  A 64×128
-augmented tall-skinny tile in block-FP at tile_b=8 is 8·64·192·4 ≈ 393 KiB
-·2, well inside the ~16 MiB VMEM of a TPU core; `autotune` searches
-tile_b under an explicit budget.  The wavefront path adds two
-(tile_b, Pmax ≤ m/2, e) pair tensors per stage (≈ the tile itself) plus
-< 1 KiB of stage tables.
+VMEM budget (DESIGN.md §5, §8): one (m, tile_b, e) tile per operand and
+result — int32 block-FP and each lane half: 2·m·tb·e·4 bytes; the stage
+pair tensors add about one more tile.  A 64×128 augmented tile at tile_b=8
+is 64·8·192·4 ≈ 393 KiB ·2, well inside the ~16 MiB scoped VMEM of a TPU
+core; `autotune` searches tile_b under an explicit budget.  The exported
+control words are (tile_b, S) int32 per word.
 """
 from __future__ import annotations
 
@@ -84,911 +78,364 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.givens import GivensConfig, GivensUnit
 from .cordic_givens import (TILE_B, comp_q30, fused_replay,
-                            fused_rotate_block, fused_rotate_ctrl,
-                            fused_rotate_pairs)
+                            fused_rotate_pairs, x32_trace)
 from .packed_lanes import LaneUnit
 
-__all__ = ["qr_packed_call", "qr_blockfp_call", "qr_packed_wavefront_call",
-           "qr_blockfp_wavefront_call", "qr_packed_complex_call",
-           "qr_packed_complex_wavefront_call", "qr_packed_lanes_call",
-           "qr_packed_lanes_wavefront_call", "panel_factor_packed_call",
-           "panel_apply_packed_call", "panel_factor_blockfp_call",
-           "panel_apply_blockfp_call", "TILE_B", "TABLE_LAYOUTS",
+__all__ = ["qr_call", "replay_call", "pad_to", "BlockFPPath", "LanePath",
+           "PackedPath", "ComplexPath", "TILE_B", "TABLE_LAYOUTS",
            "HBM_PASSES_PER_QRD"]
 
 TABLE_LAYOUTS = ("split", "stacked")
 
-#: The kernel-resident HBM-traffic contract every `*_call` here honors:
-#: the working tile is staged into VMEM once and written back once —
-#: two passes over the (B, m, e) working set per decomposition,
-#: independent of schedule length.  `repro.launch.perfmodel` builds the
-#: roofline's memory term from this.
+#: The kernel-resident HBM-traffic contract every call here honors: the
+#: working tile is staged into VMEM once and written back once — two
+#: passes over the (B, m, e) working set per decomposition, independent
+#: of schedule length.  `repro.launch.perfmodel` builds the roofline's
+#: memory term from this.
 HBM_PASSES_PER_QRD = 2
 
+_I32 = jnp.int32
 
-def _pad_batch(X, tile_b: int):
-    """Pad the leading batch axis to a multiple of tile_b with zeros.
 
-    Packed words, lane words and block-FP significands all encode exact
-    zero as the all-zero bit pattern, and the whole datapath is exact on
-    all-zero matrices (the wavefront gather already relies on this), so
-    zero-padding is harmless.  Returns (padded, original_B).
+def pad_to(x, mult, axis):
+    """Zero-pad ``axis`` of ``x`` up to a multiple of ``mult``."""
+    rem = (-x.shape[axis]) % mult
+    if rem == 0:
+        return x
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, rem)
+    return jnp.pad(x, pad)
+
+
+# ---------------------------------------------------------------------------
+# Datapaths: how one stage of row pairs rotates, and how exported control
+# words replay.  Rows arrive as one tuple entry per kernel tile (the lane
+# path carries two: hi and lo), each (P, TB, e[, 2]); ``lead`` is the
+# (P, 1, e) one-hot of each pair's leading column.  `stage` returns the
+# rotated rows and the per-pair control words, (P, TB, 1) each.
+# ---------------------------------------------------------------------------
+class BlockFPPath:
+    """int32 block-FP significands: the fused int32 CORDIC pipeline."""
+
+    wide = False
+    word_dtype = jnp.int32
+
+    def __init__(self, iters: int, hub: bool):
+        assert iters <= 30
+        self.kw = dict(iters=iters, hub=hub, comp=comp_q30(iters))
+
+    def stage(self, X, Y, lead):
+        rx, ry, flip, sig = fused_rotate_pairs(X[0], Y[0], lead, **self.kw)
+        return (rx,), (ry,), (flip, sig)
+
+    def replay(self, x, y, words):
+        rx, ry = fused_replay(x[0], y[0], *words, **self.kw)
+        return (rx,), (ry,)
+
+
+class PackedPath:
+    """int64 packed FP words through `GivensUnit` (interpret mode only)."""
+
+    wide = True
+    word_dtype = jnp.int64
+
+    def __init__(self, cfg: GivensConfig):
+        self.unit = GivensUnit(cfg)
+
+    def stage(self, X, Y, lead):
+        x, y = X[0], Y[0]
+        sel = lead.astype(x.dtype)
+        xl = jnp.sum(x * sel, axis=-1, keepdims=True)
+        yl = jnp.sum(y * sel, axis=-1, keepdims=True)
+        _, _, (flip, sig) = self.unit.vector(xl, yl)
+        # Replaying sigma on the leading column reproduces the vectoring
+        # output bit for bit, so the whole row rotates at uniform width.
+        rx, ry = self.unit.rotate(x, y, (flip, sig))
+        return (rx,), (ry,), (flip.astype(jnp.int64), sig)
+
+    def replay(self, x, y, words):
+        rx, ry = self.unit.rotate(x[0], y[0], words)
+        return (rx,), (ry,)
+
+
+class LanePath:
+    """Packed FP words as separate (hi, lo) int32 tiles, `LaneUnit`
+    arithmetic — bit-identical to `PackedPath`, and it compiles."""
+
+    wide = False
+
+    def __init__(self, cfg: GivensConfig):
+        self.unit = LaneUnit(cfg)
+
+    def stage(self, X, Y, lead):
+        sel = lead.astype(_I32)
+        # one-hot contraction: exact in int32, it only selects a word
+        xl = tuple(jnp.sum(h * sel, axis=-1, keepdims=True, dtype=_I32)
+                   for h in X)
+        yl = tuple(jnp.sum(h * sel, axis=-1, keepdims=True, dtype=_I32)
+                   for h in Y)
+        _, _, state = self.unit.vector_lanes(xl, yl)
+        rx, ry = self.unit.rotate_lanes(X, Y, state)
+        return rx, ry, ()
+
+
+class ComplexPath:
+    """Complex packed (re, im) words, three-rotation Givens (interpret
+    mode only).
+
+    Per pair: the phase control words come from vectoring on the lead
+    (re, im) pairs and replay across the whole row at uniform width; the
+    realized leads re-extracted from the phase-rotated rows drive the real
+    Givens across both lanes.  The realized pivot lead is real, so its
+    imaginary lane is forced to zero here (the target's lead is zeroed by
+    `qr_call` like every datapath's).
     """
-    B = X.shape[0]
-    pad = (-B) % tile_b
-    if pad:
-        X = jnp.pad(X, ((0, pad),) + ((0, 0),) * (X.ndim - 1))
-    return X, B
+
+    wide = True
+
+    def __init__(self, cfg: GivensConfig):
+        self.unit = GivensUnit(cfg)
+
+    def stage(self, X, Y, lead):
+        unit = self.unit
+        x, y = X[0], Y[0]
+        sel = lead.astype(x.dtype)                   # (P, 1, e) 0/1
+
+        def lead_of(v):
+            return jnp.sum(v * sel, axis=-1, keepdims=True, dtype=v.dtype)
+
+        def realize(re, im):
+            _, st, sk = unit.phase_vector(lead_of(re), lead_of(im))
+            return unit.phase_rotate(re, im, st, sk)
+
+        pxr, pxi = realize(x[..., 0], x[..., 1])
+        pyr, pyi = realize(y[..., 0], y[..., 1])
+        _, _, st = unit.vector(lead_of(pxr), lead_of(pyr))
+        rxr, ryr = unit.rotate(pxr, pyr, st)
+        rxi, ryi = unit.rotate(pxi, pyi, st)
+        rxi = jnp.where(lead, jnp.zeros_like(rxi), rxi)  # realized pivot
+        return ((jnp.stack([rxr, rxi], axis=-1),),
+                (jnp.stack([ryr, ryi], axis=-1),), ())
 
 
-def _table_operands(piv, tgt, col, table_layout: str):
-    """Stage tables -> (operands, in_specs) for the chosen layout."""
+# ---------------------------------------------------------------------------
+# Stage tables in SMEM
+# ---------------------------------------------------------------------------
+def _table_operands(tables, table_layout: str):
+    """(S, Pmax) stage tables -> flat int32 SMEM operands and their specs."""
     if table_layout not in TABLE_LAYOUTS:
         raise ValueError(f"table_layout must be one of {TABLE_LAYOUTS}, "
                          f"got {table_layout!r}")
-    S, Pmax = piv.shape
+    flat = [jnp.asarray(t, _I32).reshape(-1) for t in tables]
     if table_layout == "stacked":
-        tab = jnp.concatenate([jnp.asarray(piv), jnp.asarray(tgt),
-                               jnp.asarray(col)], axis=0)
-        return (tab,), [pl.BlockSpec((3 * S, Pmax), lambda b: (0, 0))]
-    tspec = pl.BlockSpec((S, Pmax), lambda b: (0, 0))
-    return ((jnp.asarray(piv), jnp.asarray(tgt), jnp.asarray(col)),
-            [tspec, tspec, tspec])
+        flat = [jnp.concatenate(flat)]
+    # whole-array blocks, the same on every grid cell
+    return flat, [pl.BlockSpec(memory_space=pltpu.SMEM) for _ in flat]
 
 
-def _read_tables(tab_refs, S: int, table_layout: str):
-    """Kernel-side inverse of `_table_operands` (static S slicing)."""
+def _table_reader(tab_refs, S: int, Pmax: int, table_layout: str):
+    """Kernel-side inverse of `_table_operands`: ``read(which, s, p)``."""
     if table_layout == "stacked":
         (t_ref,) = tab_refs
-        tab = t_ref[...]
-        return tab[:S], tab[S:2 * S], tab[2 * S:]
-    piv_ref, tgt_ref, col_ref = tab_refs
-    return piv_ref[...], tgt_ref[...], col_ref[...]
+        return lambda which, s, p: t_ref[(which * S + s) * Pmax + p]
+    return lambda which, s, p: tab_refs[which][s * Pmax + p]
+
+
+def _check_compiles(dp, interpret: bool):
+    if dp.wide and not interpret:
+        raise NotImplementedError(
+            f"{type(dp).__name__} carries int64 words, which no Pallas "
+            "compiler lowers: it runs in interpret mode only")
+
+
+def _bcast(mask, a):
+    """Broadcast a (P, 1, e) mask over ``a``'s trailing datapath axes."""
+    return mask.reshape(mask.shape + (1,) * (a.ndim - mask.ndim))
 
 
 # ---------------------------------------------------------------------------
-# Bit-exact packed-word kernel (int64 lanes, interpret-mode reference)
+# The factor kernel: every stage of the schedule on the resident tile
 # ---------------------------------------------------------------------------
-def _qr_packed_kernel(p_ref, o_ref, *, cfg: GivensConfig, steps):
-    """Triangularize the resident (TB, m, e) tile of packed FP words.
+def _factor_kernel(*refs, dp, n_tab: int, n_tiles: int, S: int, Pmax: int,
+                   table_layout: str, export: bool):
+    tab_refs = refs[:n_tab]
+    in_refs = refs[n_tab:n_tab + n_tiles]
+    tiles = refs[n_tab + n_tiles:n_tab + 2 * n_tiles]
+    word_refs = refs[n_tab + 2 * n_tiles:]
+    for i_ref, o_ref in zip(in_refs, tiles):
+        o_ref[...] = i_ref[...]
+    m, TB, e = tiles[0].shape[:3]
+    read = _table_reader(tab_refs, S, Pmax, table_layout)
+    col_iota = jax.lax.broadcasted_iota(_I32, (1, 1, e), 2)
+    step_iota = jax.lax.broadcasted_iota(_I32, (TB, S), 1)
 
-    Replays `qr_cordic`'s per-step dataflow with the identical `GivensUnit`
-    arithmetic, so the output words match the reference loop bit for bit.
-    """
-    unit = GivensUnit(cfg)
-    P = p_ref[...]                       # (TB, m, e) int64 packed words
-    for (k, j, col) in steps:
-        rx, ry = unit.rotate_rows(P[:, k, col:], P[:, j, col:])
-        ry = ry.at[:, 0].set(0)          # structural zero (systolic array)
-        P = P.at[:, k, col:].set(rx)
-        P = P.at[:, j, col:].set(ry)
-    o_ref[...] = P
+    def gather(rows):
+        # padded pairs (row m) read a clamped row and are never stored
+        rows = [jnp.minimum(r, m - 1) for r in rows]
+        return tuple(jnp.stack([t[r] for r in rows]) for t in tiles)
+
+    def body(s, words):
+        piv = [read(0, s, p) for p in range(Pmax)]
+        tgt = [read(1, s, p) for p in range(Pmax)]
+        col = [read(2, s, p) for p in range(Pmax)]
+        X, Y = gather(piv), gather(tgt)
+        lead = jnp.concatenate([col_iota == c for c in col])   # (P, 1, e)
+        active = jnp.concatenate([col_iota >= c for c in col])
+        rx, ry, w = dp.stage(X, Y, lead)
+        # untouched left lanes, then the structural zero of the target
+        rx = [jnp.where(_bcast(active, a), a, x) for a, x in zip(rx, X)]
+        ry = [jnp.where(_bcast(lead, a), jnp.zeros_like(a),
+                        jnp.where(_bcast(active, a), a, y))
+              for a, y in zip(ry, Y)]
+        for p in range(Pmax):
+            def store(p=p):
+                for t, a, b in zip(tiles, rx, ry):
+                    t[piv[p]] = a[p]
+                    t[tgt[p]] = b[p]
+            if Pmax == 1:          # step tables carry no padding
+                store()
+            else:
+                pl.when(piv[p] < m)(store)
+        if not export:
+            return words
+        return tuple(jnp.where(step_iota == s, v[0], acc)
+                     for v, acc in zip(w, words))
+
+    init = tuple(jnp.zeros((TB, S), dp.word_dtype) for _ in word_refs)
+    words = jax.lax.fori_loop(_I32(0), _I32(S), body, init)
+    for ref, acc in zip(word_refs, words):
+        ref[...] = acc
 
 
-def qr_packed_call(P, *, cfg: GivensConfig, steps, interpret: bool = True,
-                   tile_b: int = TILE_B):
-    """Blocked QR over packed FP words, one grid cell per tile_b matrices.
+def qr_call(tiles, tables, dp, *, interpret: bool, tile_b: int = TILE_B,
+            table_layout: str = "split", export: bool = False):
+    """Run a stage schedule over rows-first working tiles in one kernel.
 
     Parameters
     ----------
-    P : (B, m, e) int64
-        Packed FP words of the augmented working matrices ([A | I] rows for
-        a full QRD).  Ragged ``B`` is padded to a multiple of ``tile_b``
-        with zero matrices and sliced back.
-    cfg : GivensConfig
-        Static unit configuration (format, N, iters, HUB flags).
-    steps : tuple[(int, int, int), ...]
-        Static rotation schedule ``(pivot_row, target_row, col)``.
+    tiles : tuple of (m, B, e[, 2]) arrays
+        The working matrices, rows first — one array per datapath tile
+        (two for `LanePath`: hi, lo).  Ragged ``B`` is padded to a
+        multiple of ``tile_b`` with zero matrices and sliced back.
+    tables : (piv, tgt, col), each (S, Pmax) int
+        Stage tables (`repro.kernels.ops.stage_tables` / `step_tables`):
+        pivot rows, target rows, leading columns; padded pairs carry
+        row ``m``.
+    dp : BlockFPPath | LanePath | PackedPath | ComplexPath
+        The datapath.
     interpret : bool
-        int64 lanes: interpret mode only — the compiled path is
-        `qr_packed_lanes_call` on the hi/lo split of the same words.
+        Pallas interpret mode.  The int64 datapaths exist only there.
+    export : bool
+        Also return each step's control words, (B, S) per word, for
+        `replay_call` (one pair per stage only: the panel factor).
 
     Returns
     -------
-    (B, m, e) int64 — the triangularized packed working matrices.
+    tuple of rotated tiles (same shapes), followed by the exported words
+    when ``export``.
     """
-    P, B = _pad_batch(P, tile_b)
-    Bp, m, e = P.shape
-    grid = (Bp // tile_b,)
-    spec = pl.BlockSpec((tile_b, m, e), lambda b: (b, 0, 0))
-    kernel = functools.partial(_qr_packed_kernel, cfg=cfg, steps=tuple(steps))
-    out = pl.pallas_call(
-        kernel, grid=grid,
-        in_specs=[spec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((Bp, m, e), jnp.int64),
-        interpret=interpret,
-    )(P)
-    return out[:B]
+    _check_compiles(dp, interpret)
+    S, Pmax = tables[0].shape
+    assert not export or Pmax == 1
+    B = tiles[0].shape[1]
+    tiles = tuple(pad_to(t, tile_b, 1) for t in tiles)
+    m, Bp, e = tiles[0].shape[:3]
+
+    def spec(t):
+        rest = t.shape[3:]
+        return pl.BlockSpec((m, tile_b, e) + rest,
+                            lambda b: (0, b, 0) + (0,) * len(rest))
+
+    tab_ops, tab_specs = _table_operands(tables, table_layout)
+    out_specs = [spec(t) for t in tiles]
+    out_shape = [jax.ShapeDtypeStruct(t.shape, t.dtype) for t in tiles]
+    if export:                             # (flip, sigma) per step
+        out_specs += [pl.BlockSpec((tile_b, S), lambda b: (b, 0))] * 2
+        out_shape += [jax.ShapeDtypeStruct((Bp, S), dp.word_dtype)] * 2
+    kernel = functools.partial(
+        _factor_kernel, dp=dp, n_tab=len(tab_ops), n_tiles=len(tiles), S=S,
+        Pmax=Pmax, table_layout=table_layout, export=export)
+    with x32_trace(not dp.wide):
+        out = pl.pallas_call(
+            kernel, grid=(Bp // tile_b,),
+            in_specs=[*tab_specs, *[spec(t) for t in tiles]],
+            out_specs=out_specs,
+            out_shape=out_shape,
+            interpret=interpret,
+        )(*tab_ops, *tiles)
+    outs = tuple(o[:, :B] for o in out[:len(tiles)])
+    return outs + tuple(w[:B] for w in out[len(tiles):])
 
 
 # ---------------------------------------------------------------------------
-# Bit-exact packed-word kernels on dual int32 lanes (the compilable path)
+# The replay kernel: exported control words over trailing panels
 # ---------------------------------------------------------------------------
-def _qr_packed_lanes_kernel(p_ref, o_ref, *, cfg: GivensConfig, steps):
-    """`_qr_packed_kernel` on the (TB, m, e, 2) hi/lo lane tile.
-
-    The `LaneUnit` emulates the unit's int64 arithmetic over (hi, lo)
-    int32 pairs (`repro.kernels.packed_lanes`), so this body contains no
-    64-bit types and lowers through Mosaic/Triton.  Bit-identical to the
-    int64 kernel on `lanes_to_packed` of the result.
-    """
-    unit = LaneUnit(cfg)
-    P = p_ref[...]                       # (TB, m, e, 2) int32 lane words
-    for (k, j, col) in steps:
-        rx, ry = unit.rotate_rows(P[:, k, col:, :], P[:, j, col:, :])
-        ry = ry.at[:, 0, :].set(0)       # structural zero (both lanes)
-        P = P.at[:, k, col:, :].set(rx)
-        P = P.at[:, j, col:, :].set(ry)
-    o_ref[...] = P
-
-
-def qr_packed_lanes_call(P, *, cfg: GivensConfig, steps,
-                         interpret: bool = False, tile_b: int = TILE_B):
-    """Blocked QR over dual-int32 packed lane words (compilable bit-exact).
-
-    Parameters as `qr_packed_call` with ``P : (B, m, e, 2) int32`` from
-    `cordic_givens.packed_to_lanes`; returns the rotated lane words,
-    satisfying ``lanes_to_packed(out) == qr_packed_call(packed)`` bit for
-    bit.  ``interpret`` defaults to False — this datapath exists to
-    compile; pass True on CPU (ops.py auto-selects).
-    """
-    P, B = _pad_batch(P, tile_b)
-    Bp, m, e, two = P.shape
-    assert two == 2
-    grid = (Bp // tile_b,)
-    spec = pl.BlockSpec((tile_b, m, e, 2), lambda b: (b, 0, 0, 0))
-    kernel = functools.partial(_qr_packed_lanes_kernel, cfg=cfg,
-                               steps=tuple(steps))
-    out = pl.pallas_call(
-        kernel, grid=grid,
-        in_specs=[spec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((Bp, m, e, 2), jnp.int32),
-        interpret=interpret,
-    )(P)
-    return out[:B]
-
-
-def _wavefront_scan_lanes(P, tables, stage_fn):
-    """`_wavefront_scan` for tiles with a trailing lane axis (TB, m, e, 2).
-
-    The element-axis masks gain a trailing singleton so they broadcast
-    across the (hi, lo) lanes; the structural zero forces both lanes (the
-    packed zero word is the all-zero bit pattern).
-    """
-    TB, m, e, _ = P.shape
-
-    def body(P, tab):
-        piv, tgt, col = tab
-        X = jnp.take(P, piv, axis=1, mode="fill", fill_value=0)
-        Y = jnp.take(P, tgt, axis=1, mode="fill", fill_value=0)
-        colid = jax.lax.broadcasted_iota(jnp.int32, (col.shape[0], e), 1)
-        lead = colid == col[:, None]                      # (P, e)
-        active = (colid >= col[:, None])[None, ..., None]
-        rx, ry = stage_fn(X, Y, lead)
-        rx = jnp.where(active, rx, X)                # untouched left lanes
-        ry = jnp.where(active, ry, Y)
-        ry = jnp.where(lead[None, ..., None], 0, ry)      # structural zero
-        P = P.at[:, piv, :, :].set(rx, mode="drop")
-        P = P.at[:, tgt, :, :].set(ry, mode="drop")
-        return P, None
-
-    P, _ = jax.lax.scan(body, P, tables)
-    return P
-
-
-def _qr_packed_lanes_wavefront_kernel(*refs, cfg: GivensConfig, S: int,
-                                      table_layout: str):
-    """Wavefront triangularization of the resident (TB, m, e, 2) lane tile.
-
-    The lane-pair mirror of `_qr_packed_wavefront_kernel`: same stage
-    machinery, `LaneUnit` arithmetic, one-hot lead contraction over the
-    element axis per lane (exact — the contraction just selects words).
-    """
-    *tab_refs, p_ref, o_ref = refs
-    unit = LaneUnit(cfg)
-
-    def stage(X, Y, lead):
-        sel = lead[None, ..., None].astype(X.dtype)       # (1, P, e, 1)
-        xl = jnp.sum(X * sel, axis=-2, dtype=X.dtype)     # (TB, P, 2)
-        yl = jnp.sum(Y * sel, axis=-2, dtype=Y.dtype)
-        _, _, (flip, sig) = unit.vector(xl, yl)
-        return unit.rotate(X, Y, (flip[..., None], sig[..., None, :]))
-
-    tables = _read_tables(tab_refs, S, table_layout)
-    o_ref[...] = _wavefront_scan_lanes(p_ref[...], tables, stage)
-
-
-def qr_packed_lanes_wavefront_call(P, piv, tgt, col, *, cfg: GivensConfig,
-                                   interpret: bool = False,
-                                   tile_b: int = TILE_B,
-                                   table_layout: str = "split"):
-    """Wavefront blocked QR over dual-int32 packed lane words.
-
-    Parameters as `qr_packed_wavefront_call` with the (B, m, e, 2) lane
-    operand of `qr_packed_lanes_call`; bit-identical to it on the
-    flattened stage schedule.
-    """
-    P, B = _pad_batch(P, tile_b)
-    Bp, m, e, two = P.shape
-    assert two == 2
-    S, Pmax = piv.shape
-    grid = (Bp // tile_b,)
-    spec = pl.BlockSpec((tile_b, m, e, 2), lambda b: (b, 0, 0, 0))
-    tab_ops, tab_specs = _table_operands(piv, tgt, col, table_layout)
-    kernel = functools.partial(_qr_packed_lanes_wavefront_kernel, cfg=cfg,
-                               S=S, table_layout=table_layout)
-    out = pl.pallas_call(
-        kernel, grid=grid,
-        in_specs=[*tab_specs, spec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((Bp, m, e, 2), jnp.int32),
-        interpret=interpret,
-    )(*tab_ops, P)
-    return out[:B]
-
-
-# ---------------------------------------------------------------------------
-# Complex packed-word kernels: three-rotation Givens on (re, im) lane pairs
-# (DESIGN.md §10).  The resident tile gains a trailing axis of size 2; the
-# schedule machinery (static step unroll / stage-table scan) is unchanged.
-# int64 lanes (interpret mode) — the dual-lane split covers the real
-# datapath only today (DESIGN.md §11).
-# ---------------------------------------------------------------------------
-def _qr_packed_complex_kernel(p_ref, o_ref, *, cfg: GivensConfig, steps):
-    """Triangularize the resident (TB, m, e, 2) tile of packed re/im lanes.
-
-    Replays `qr_cordic_complex`'s per-step three-rotation dataflow with
-    the identical `GivensUnit` arithmetic, so the output words match the
-    host reference loop bit for bit (IEEE and HUB).
-    """
-    unit = GivensUnit(cfg)
-    P = p_ref[...]                       # (TB, m, e, 2) int64 packed words
-    for (k, j, col) in steps:
-        rx, ry = unit.rotate_rows_complex(P[:, k, col:, :], P[:, j, col:, :])
-        P = P.at[:, k, col:, :].set(rx)
-        P = P.at[:, j, col:, :].set(ry)
-    o_ref[...] = P
-
-
-def qr_packed_complex_call(P, *, cfg: GivensConfig, steps,
-                           interpret: bool = True, tile_b: int = TILE_B):
-    """Blocked complex QR over packed (re, im) lane pairs.
-
-    Parameters
-    ----------
-    P : (B, m, e, 2) int64
-        Packed FP words of the augmented complex working matrices; the
-        trailing axis holds the (re, im) lanes.  Ragged ``B`` is padded
-        to a multiple of ``tile_b`` and sliced back.
-    cfg, steps, interpret : as `qr_packed_call`.
-
-    Returns
-    -------
-    (B, m, e, 2) int64 — triangularized packed words, bit-identical to
-    the `qr_cordic_complex` reference loop.
-    """
-    P, B = _pad_batch(P, tile_b)
-    Bp, m, e, two = P.shape
-    assert two == 2
-    grid = (Bp // tile_b,)
-    spec = pl.BlockSpec((tile_b, m, e, 2), lambda b: (b, 0, 0, 0))
-    kernel = functools.partial(_qr_packed_complex_kernel, cfg=cfg,
-                               steps=tuple(steps))
-    out = pl.pallas_call(
-        kernel, grid=grid,
-        in_specs=[spec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((Bp, m, e, 2), jnp.int64),
-        interpret=interpret,
-    )(P)
-    return out[:B]
-
-
-def _wavefront_scan_complex(P, tables, stage_fn):
-    """Complex counterpart of `_wavefront_scan` on a (TB, m, e, 2) tile.
-
-    Identical gather/scatter machinery with the (re, im) lane axis riding
-    along; the per-pair column masks are unchanged — they address the
-    element axis and broadcast across the re/im lanes.  The structural
-    zeros of the complex step are forced here: the annihilated target
-    lead (both lanes) and the imaginary lane of the realized pivot lead.
-    """
-    TB, m, e, _ = P.shape
-
-    def body(P, tab):
-        piv, tgt, col = tab
-        X = jnp.take(P, piv, axis=1, mode="fill", fill_value=0)
-        Y = jnp.take(P, tgt, axis=1, mode="fill", fill_value=0)
-        colid = jax.lax.broadcasted_iota(jnp.int32, (col.shape[0], e), 1)
-        lead = (colid == col[:, None])[None, ..., None]   # (1, P, e, 1)
-        active = (colid >= col[:, None])[None, ..., None]
-        rx, ry = stage_fn(X, Y, lead[0, ..., 0])
-        rx = jnp.where(active, rx, X)                # untouched left lanes
-        ry = jnp.where(active, ry, Y)
-        im = jnp.arange(2) == 1
-        rx = jnp.where(lead & im, 0, rx)             # realized pivot is real
-        ry = jnp.where(lead, 0, ry)                  # structural zero
-        P = P.at[:, piv, :, :].set(rx, mode="drop")
-        P = P.at[:, tgt, :, :].set(ry, mode="drop")
-        return P, None
-
-    P, _ = jax.lax.scan(body, P, tables)
-    return P
-
-
-def _qr_packed_complex_wavefront_kernel(*refs, cfg: GivensConfig, S: int,
-                                        table_layout: str):
-    """Wavefront complex triangularization of the resident (TB, m, e, 2) tile.
-
-    One scan step per Sameh–Kuck stage: every pair of the stage runs the
-    three-rotation decomposition along a (TB, P, e) pair axis — the phase
-    control words come from vectoring on the gathered lead (re, im)
-    pairs, replay across the whole row at uniform width (replaying a
-    control word on the pair that produced it reproduces the vectoring
-    output bit for bit), and the realized leads re-extracted from the
-    phase-rotated rows drive the real Givens across both lanes.
-    Bit-identical to `_qr_packed_complex_kernel` on the flattened stage
-    schedule.
-    """
-    *tab_refs, p_ref, o_ref = refs
-    unit = GivensUnit(cfg)
-
-    def stage(X, Y, lead):
-        sel = lead[None].astype(X.dtype)             # (1, P, e) 0/1
-        xr, xi = X[..., 0], X[..., 1]                # (TB, P, e)
-        yr, yi = Y[..., 0], Y[..., 1]
-        _, stx, skx = unit.phase_vector(
-            jnp.sum(xr * sel, axis=-1, dtype=X.dtype),
-            jnp.sum(xi * sel, axis=-1, dtype=X.dtype))
-        _, sty, sky = unit.phase_vector(
-            jnp.sum(yr * sel, axis=-1, dtype=Y.dtype),
-            jnp.sum(yi * sel, axis=-1, dtype=Y.dtype))
-        pxr, pxi = unit.phase_rotate(
-            xr, xi, (stx[0][..., None], stx[1][..., None]), skx[..., None])
-        pyr, pyi = unit.phase_rotate(
-            yr, yi, (sty[0][..., None], sty[1][..., None]), sky[..., None])
-        magx = jnp.sum(pxr * sel, axis=-1, dtype=X.dtype)
-        magy = jnp.sum(pyr * sel, axis=-1, dtype=Y.dtype)
-        _, _, (flip, sig) = unit.vector(magx, magy)
-        st_b = (flip[..., None], sig[..., None])
-        rxr, ryr = unit.rotate(pxr, pyr, st_b)
-        rxi, ryi = unit.rotate(pxi, pyi, st_b)
-        return (jnp.stack([rxr, rxi], axis=-1),
-                jnp.stack([ryr, ryi], axis=-1))
-
-    tables = _read_tables(tab_refs, S, table_layout)
-    o_ref[...] = _wavefront_scan_complex(p_ref[...], tables, stage)
-
-
-def qr_packed_complex_wavefront_call(P, piv, tgt, col, *, cfg: GivensConfig,
-                                     interpret: bool = True,
-                                     tile_b: int = TILE_B,
-                                     table_layout: str = "split"):
-    """Wavefront blocked complex QR over packed (re, im) lane pairs.
-
-    Parameters as `qr_packed_wavefront_call` with the (B, m, e, 2)
-    operand of `qr_packed_complex_call`.
-
-    Returns
-    -------
-    (B, m, e, 2) int64 — triangularized packed words, bit-identical to
-    `qr_packed_complex_call` on the flattened stage schedule.
-    """
-    P, B = _pad_batch(P, tile_b)
-    Bp, m, e, two = P.shape
-    assert two == 2
-    S, Pmax = piv.shape
-    grid = (Bp // tile_b,)
-    spec = pl.BlockSpec((tile_b, m, e, 2), lambda b: (b, 0, 0, 0))
-    tab_ops, tab_specs = _table_operands(piv, tgt, col, table_layout)
-    kernel = functools.partial(_qr_packed_complex_wavefront_kernel, cfg=cfg,
-                               S=S, table_layout=table_layout)
-    out = pl.pallas_call(
-        kernel, grid=grid,
-        in_specs=[*tab_specs, spec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((Bp, m, e, 2), jnp.int64),
-        interpret=interpret,
-    )(*tab_ops, P)
-    return out[:B]
-
-
-# ---------------------------------------------------------------------------
-# int32 block-fixed-point kernel (significand-resident fast path)
-# ---------------------------------------------------------------------------
-def _qr_blockfp_kernel(x_ref, o_ref, *, iters: int, hub: bool, comp: int,
-                       steps):
-    X = x_ref[...]                       # (TB, m, e) int32 significands
-    for (k, j, col) in steps:
-        rx, ry = fused_rotate_block(X[:, k, col:], X[:, j, col:],
-                                    iters=iters, hub=hub, comp=comp)
-        ry = ry.at[:, 0].set(0)
-        X = X.at[:, k, col:].set(rx)
-        X = X.at[:, j, col:].set(ry)
-    o_ref[...] = X
-
-
-def _wavefront_scan(P, tables, stage_fn):
-    """Run `stage_fn` over every Sameh–Kuck stage of the resident tile.
-
-    P : (TB, m, e) resident working tile (packed int64 or block-FP int32).
-    tables : three (S, Pmax) int32 arrays — pivot rows, target rows,
-        leading columns, one row per stage, padded with ``piv = tgt = m``.
-    stage_fn : (X, Y, lead) -> (rx, ry) — the pair-axis rotation on the
-        gathered (TB, Pmax, e) pivot/target tensors, with `lead` the
-        (Pmax, e) one-hot of each pair's leading column.
-
-    One `lax.scan` iteration per stage: gather the stage's pivot and
-    target rows into two (TB, Pmax, e) pair tensors, rotate the whole pair
-    axis at uniform width e, restore the left-of-lead lanes from the
-    inputs (they belong to earlier, already-annihilated columns, which the
-    sequential path never touches), force the structural zero, and scatter
-    the rotated rows back.  The padding convention makes both transfers
-    total functions: padded pairs carry the out-of-range row index ``m``,
-    so the mode='fill' gather hands them all-zero rows (harmless through
-    the integer datapath) and the mode='drop' scatter discards their
-    updates — deterministically, since within a stage the real row indices
-    are disjoint by construction.  Sequential depth is the number of
-    stages, not the number of rotations.
-    """
-    TB, m, e = P.shape
-
-    def body(P, tab):
-        piv, tgt, col = tab
-        X = jnp.take(P, piv, axis=1, mode="fill", fill_value=0)
-        Y = jnp.take(P, tgt, axis=1, mode="fill", fill_value=0)
-        colid = jax.lax.broadcasted_iota(jnp.int32, (col.shape[0], e), 1)
-        lead = colid == col[:, None]
-        active = colid >= col[:, None]
-        rx, ry = stage_fn(X, Y, lead)
-        rx = jnp.where(active[None], rx, X)          # untouched left lanes
-        ry = jnp.where(active[None], ry, Y)
-        ry = jnp.where(lead[None], 0, ry)            # structural zero
-        P = P.at[:, piv, :].set(rx, mode="drop")
-        P = P.at[:, tgt, :].set(ry, mode="drop")
-        return P, None
-
-    P, _ = jax.lax.scan(body, P, tables)
-    return P
-
-
-def _qr_packed_wavefront_kernel(*refs, cfg: GivensConfig, S: int,
-                                table_layout: str):
-    """Wavefront triangularization of the resident packed (TB, m, e) tile.
-
-    Same `GivensUnit` arithmetic as `_qr_packed_kernel`, but one scan step
-    per Sameh–Kuck *stage*: every pair of the stage runs the full
-    input-convert → vectoring → sigma-replay → gain → output-convert
-    dataflow along a (TB, P, e) pair axis.  Within-stage rotations touch
-    disjoint rows, so the result is bit-identical to replaying the
-    flattened schedule pair by pair.
-    """
-    *tab_refs, p_ref, o_ref = refs
-    unit = GivensUnit(cfg)
-
-    def stage(X, Y, lead):
-        sel = lead[None].astype(X.dtype)
-        xl = jnp.sum(X * sel, axis=-1)               # (TB, P) leading pair
-        yl = jnp.sum(Y * sel, axis=-1)
-        _, _, (flip, sig) = unit.vector(xl, yl)
-        # Replaying sigma on the leading column reproduces the vectoring
-        # output bit for bit, so the whole row rotates at uniform width.
-        return unit.rotate(X, Y, (flip[..., None], sig[..., None]))
-
-    tables = _read_tables(tab_refs, S, table_layout)
-    o_ref[...] = _wavefront_scan(p_ref[...], tables, stage)
-
-
-def qr_packed_wavefront_call(P, piv, tgt, col, *, cfg: GivensConfig,
-                             interpret: bool = True, tile_b: int = TILE_B,
-                             table_layout: str = "split"):
-    """Wavefront blocked QR over packed FP words (bit-exact path).
-
-    Parameters
-    ----------
-    P : (B, m, e) int64
-        Packed FP words of the augmented working matrices; ragged ``B``
-        is padded to a multiple of ``tile_b`` and sliced back.
-    piv, tgt, col : (S, Pmax) int32
-        Stage index tables — one row per Sameh–Kuck stage, padded with
-        ``piv = tgt = m`` / ``col = 0`` (see `ops._stage_tables`).
-    cfg : GivensConfig
-        Static unit configuration.
-    table_layout : 'split' | 'stacked'
-        How the stage tables travel to the kernel (autotuner dimension).
-
-    Returns
-    -------
-    (B, m, e) int64 — triangularized packed words, bit-identical to
-    `qr_packed_call` on the flattened stage schedule.
-    """
-    P, B = _pad_batch(P, tile_b)
-    Bp, m, e = P.shape
-    S, Pmax = piv.shape
-    grid = (Bp // tile_b,)
-    spec = pl.BlockSpec((tile_b, m, e), lambda b: (b, 0, 0))
-    tab_ops, tab_specs = _table_operands(piv, tgt, col, table_layout)
-    kernel = functools.partial(_qr_packed_wavefront_kernel, cfg=cfg,
-                               S=S, table_layout=table_layout)
-    out = pl.pallas_call(
-        kernel, grid=grid,
-        in_specs=[*tab_specs, spec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((Bp, m, e), jnp.int64),
-        interpret=interpret,
-    )(*tab_ops, P)
-    return out[:B]
-
-
-def _qr_blockfp_wavefront_kernel(*refs, iters: int, hub: bool, comp: int,
-                                 S: int, table_layout: str):
-    *tab_refs, x_ref, o_ref = refs
-    stage = functools.partial(fused_rotate_pairs, iters=iters, hub=hub,
-                              comp=comp)
-    tables = _read_tables(tab_refs, S, table_layout)
-    o_ref[...] = _wavefront_scan(x_ref[...], tables, stage)
-
-
-def qr_blockfp_wavefront_call(X, piv, tgt, col, *, iters: int, hub: bool,
-                              interpret: bool = True, tile_b: int = TILE_B,
-                              table_layout: str = "split"):
-    """Wavefront blocked QR over int32 block-FP significands.
-
-    Parameters as `qr_blockfp_call`, with the static step schedule replaced
-    by the (S, Pmax) stage index tables of `qr_packed_wavefront_call`.
-    Bit-identical to `qr_blockfp_call` on the flattened stage schedule
-    (within-stage pairs are disjoint; the pair-axis datapath replays the
-    same int32 recurrence).
-    """
-    X, B = _pad_batch(X, tile_b)
-    Bp, m, e = X.shape
-    assert iters <= 30
-    S, Pmax = piv.shape
-    grid = (Bp // tile_b,)
-    spec = pl.BlockSpec((tile_b, m, e), lambda b: (b, 0, 0))
-    tab_ops, tab_specs = _table_operands(piv, tgt, col, table_layout)
-    kernel = functools.partial(_qr_blockfp_wavefront_kernel, iters=iters,
-                               hub=hub, comp=comp_q30(iters), S=S,
-                               table_layout=table_layout)
-    out = pl.pallas_call(
-        kernel, grid=grid,
-        in_specs=[*tab_specs, spec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((Bp, m, e), jnp.int32),
-        interpret=interpret,
-    )(*tab_ops, X)
-    return out[:B]
-
-
-def qr_blockfp_call(X, *, iters: int, hub: bool, steps,
-                    interpret: bool = True, tile_b: int = TILE_B):
-    """Blocked QR over int32 block-FP significands (single decode at end).
-
-    Parameters
-    ----------
-    X : (B, m, e) int32
-        Significands with F fraction bits, one shared exponent per
-        (matrix, column) — see `ops.givens_block_apply` for the
-        quantization.  |X| ≤ 2^F on entry; the two CORDIC growth bits plus
-        column-norm accumulation (≤ √m) must keep intermediates inside
-        int32, so F = 24 supports m up to ~64.  Ragged ``B`` is padded to
-        a multiple of ``tile_b`` and sliced back.
-    iters, hub : static CORDIC depth and HUB/conventional arithmetic.
-    steps : static (pivot, target, col) schedule.
-
-    Returns
-    -------
-    (B, m, e) int32 — triangularized significands (same per-column scale).
-    """
-    X, B = _pad_batch(X, tile_b)
-    Bp, m, e = X.shape
-    assert iters <= 30
-    grid = (Bp // tile_b,)
-    spec = pl.BlockSpec((tile_b, m, e), lambda b: (b, 0, 0))
-    kernel = functools.partial(_qr_blockfp_kernel, iters=iters, hub=hub,
-                               comp=comp_q30(iters), steps=tuple(steps))
-    out = pl.pallas_call(
-        kernel, grid=grid,
-        in_specs=[spec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((Bp, m, e), jnp.int32),
-        interpret=interpret,
-    )(X)
-    return out[:B]
-
-
-# ---------------------------------------------------------------------------
-# Tiled panel QR (DESIGN.md §14): factor a resident (TB, mr, pw) panel while
-# *exporting* its rotation control words, then replay them over the trailing
-# panels with a second kernel whose grid is batched over the trailing-panel
-# axis — the paper's compute-once/replay-everywhere contract extended across
-# kernel launches.  The step machinery is a `lax.scan` over (S,) local step
-# tables (pivot row, target row, column — all panel-relative), NOT the
-# unrolled straight-line body of the flat kernels: a 64-wide panel carries
-# hundreds of steps and the scan keeps the trace at one body.
-#
-# Bit-exactness: with the column-major schedule the panel decomposition
-# replays the *identical* rotation sequence as the flat kernel — each
-# rotation is elementwise in the column axis once its (flip, sigma) word is
-# fixed, so deferring the trailing-panel columns to the apply kernel cannot
-# change a single bit (tests assert equality against `qr_packed_call`).
-# The uniform-width rotate + left-lane restore is the wavefront convention
-# (`_wavefront_scan`); replaying sigma on the lead reproduces vectoring
-# bit for bit.
-# ---------------------------------------------------------------------------
-def _panel_factor_packed_kernel(piv_ref, tgt_ref, col_ref, p_ref,
-                                o_ref, f_ref, s_ref, *, cfg: GivensConfig):
-    """Factor the resident (TB, mr, pw) packed panel, exporting controls.
-
-    One scan step per schedule entry: gather the pivot/target rows by the
-    traced step index, vector on the lead column (one-hot contraction),
-    rotate the pair at uniform panel width, restore the left-of-lead
-    lanes, force the structural zero, scatter back — and emit the step's
-    (flip, sigma) words into the (TB, S) control outputs.
-    """
-    unit = GivensUnit(cfg)
-    P = p_ref[...]                       # (TB, mr, pw) int64 packed words
-    pw = P.shape[-1]
-    colid = jax.lax.broadcasted_iota(jnp.int32, (1, pw), 1)
-
-    def body(P, tab):
-        piv, tgt, col = tab
-        x = P[:, piv]                    # (TB, pw)
-        y = P[:, tgt]
-        lead = colid == col              # (1, pw)
-        active = colid >= col
-        sel = lead.astype(x.dtype)
-        xl = jnp.sum(x * sel, axis=-1)   # (TB,) leading pair
-        yl = jnp.sum(y * sel, axis=-1)
-        _, _, (flip, sig) = unit.vector(xl, yl)
-        rx, ry = unit.rotate(x, y, (flip[..., None], sig[..., None]))
-        rx = jnp.where(active, rx, x)    # untouched left lanes
-        ry = jnp.where(active, ry, y)
-        ry = jnp.where(lead, 0, ry)      # structural zero
-        P = P.at[:, piv].set(rx)  # lint: allow[unguarded-scatter] piv != tgt per step by schedule
-        P = P.at[:, tgt].set(ry)
-        return P, (flip, sig)
-
-    tables = (piv_ref[...], tgt_ref[...], col_ref[...])
-    P, (flips, sigs) = jax.lax.scan(body, P, tables)
-    o_ref[...] = P
-    f_ref[...] = jnp.transpose(flips)    # (S, TB) -> (TB, S)
-    s_ref[...] = jnp.transpose(sigs)
-
-
-def panel_factor_packed_call(P, piv, tgt, col, *, cfg: GivensConfig,
-                             interpret: bool = True, tile_b: int = TILE_B):
-    """Panel factorization over packed FP words, exporting control words.
-
-    Parameters
-    ----------
-    P : (B, mr, pw) int64
-        Packed FP words of one panel — the ``mr`` resident rows are the
-        not-yet-finalized rows of the full working matrix (global rows
-        ``c0..m-1`` for the panel starting at column ``c0``), ``pw`` its
-        columns.  Ragged ``B`` is padded with zero matrices, as
-        everywhere here.
-    piv, tgt, col : (S,) int32
-        Panel-local step tables (`ops.panel_steps`) — the column-major
-        schedule restricted to this panel, rows relative to the panel.
-    cfg : GivensConfig
-        Static unit configuration.  int64 lanes: interpret mode only,
-        like `qr_packed_call`.
-
-    Returns
-    -------
-    (out, flip, sig)
-        ``out`` (B, mr, pw) int64 — the factored panel (upper-triangular
-        head over zeros); ``flip``/``sig`` (B, S) int64 — the exported
-        per-step control words, replayable over any trailing panel via
-        `panel_apply_packed_call`.
-    """
-    P, B = _pad_batch(P, tile_b)
-    Bp, mr, pw = P.shape
-    S = piv.shape[0]
-    grid = (Bp // tile_b,)
-    spec = pl.BlockSpec((tile_b, mr, pw), lambda b: (b, 0, 0))
-    cspec = pl.BlockSpec((tile_b, S), lambda b: (b, 0))
-    tspec = pl.BlockSpec((S,), lambda b: (0,))
-    kernel = functools.partial(_panel_factor_packed_kernel, cfg=cfg)
-    out, flip, sig = pl.pallas_call(
-        kernel, grid=grid,
-        in_specs=[tspec, tspec, tspec, spec],
-        out_specs=[spec, cspec, cspec],
-        out_shape=[jax.ShapeDtypeStruct((Bp, mr, pw), jnp.int64),
-                   jax.ShapeDtypeStruct((Bp, S), jnp.int64),
-                   jax.ShapeDtypeStruct((Bp, S), jnp.int64)],
-        interpret=interpret,
-    )(jnp.asarray(piv), jnp.asarray(tgt), jnp.asarray(col), P)
-    return out[:B], flip[:B], sig[:B]
-
-
-def _panel_apply_packed_kernel(piv_ref, tgt_ref, f_ref, s_ref, t_ref, o_ref,
-                               *, cfg: GivensConfig):
-    """Replay a panel's exported control words on one trailing tile.
-
-    The resident tile is one (TB, mr, pw) trailing-panel block; every
-    element is active (the rotation set touches whole rows right of the
-    factored panel), so no column masks are needed — just the scan over
-    the (piv, tgt, flip, sigma) step stream.
-    """
-    unit = GivensUnit(cfg)
-    T = t_ref[...][:, 0]                 # (TB, 1, mr, pw) -> (TB, mr, pw)
-    flips = jnp.transpose(f_ref[...])    # (TB, S) -> (S, TB) scan stream
-    sigs = jnp.transpose(s_ref[...])
-
-    def body(T, tab):
-        piv, tgt, flip, sig = tab
-        rx, ry = unit.rotate(T[:, piv], T[:, tgt],
-                             (flip[..., None], sig[..., None]))
-        T = T.at[:, piv].set(rx)  # lint: allow[unguarded-scatter] piv != tgt per step by schedule
-        T = T.at[:, tgt].set(ry)
-        return T, None
-
-    T, _ = jax.lax.scan(body, T, (piv_ref[...], tgt_ref[...], flips, sigs))
-    o_ref[...] = T[:, None]
-
-
-def panel_apply_packed_call(T, piv, tgt, flip, sig, *, cfg: GivensConfig,
-                            interpret: bool = True, tile_b: int = TILE_B):
+def _replay_kernel(piv_ref, tgt_ref, *refs, dp, S: int):
+    *word_refs, t_in, t = refs
+    t[...] = t_in[...]
+    read = _table_reader((piv_ref, tgt_ref), S, 1, "split")
+    words = tuple(r[...] for r in word_refs)         # (TB, S) each
+    step_iota = jax.lax.broadcasted_iota(_I32, words[0].shape, 1)
+
+    def body(s, carry):
+        k, j = read(0, s, 0), read(1, s, 0)
+        # column s of each word table, as a (TB, 1) lane-broadcast word
+        w = tuple(jnp.sum(jnp.where(step_iota == s, W, jnp.zeros_like(W)),
+                          axis=1, keepdims=True, dtype=W.dtype)
+                  for W in words)
+        (rx,), (ry,) = dp.replay((t[k],), (t[j],), w)
+        t[k] = rx
+        t[j] = ry
+        return carry
+
+    jax.lax.fori_loop(_I32(0), _I32(S), body, 0)
+
+
+def replay_call(T, piv, tgt, words, dp, *, interpret: bool,
+                tile_b: int = TILE_B):
     """Replay exported panel controls over the trailing panels.
 
     The grid is (batch tiles, trailing panels): each cell replays the
-    full (S,) rotation set on one (tile_b, mr, pw) trailing block — the
-    trailing-panel axis rides the Pallas grid, not just ``tile_b``, so
-    wide trailing regions parallelize across cells instead of growing
-    the resident tile.
+    full (S,) rotation set on one (mr, tile_b, pw) trailing block — the
+    trailing-panel axis rides the Pallas grid, so wide trailing regions
+    parallelize across cells instead of growing the resident tile.
 
     Parameters
     ----------
-    T : (B, G, mr, pw)
-        The trailing region, chunked into G panel-width tiles (zero-pad
-        the last chunk; rotations are columnwise, so pad columns never
-        feed back into real ones).
-    piv, tgt : (S,) int32 — panel-local step row tables.
-    flip, sig : (B, S) int64 — control words from
-        `panel_factor_packed_call`.
+    T : (G, mr, B, pw)
+        The trailing region, rows first, chunked into G panel-width tiles
+        (zero-pad the last chunk; rotations are columnwise, so pad
+        columns never feed back into real ones).
+    piv, tgt : (S,) int — panel-local step row tables.
+    words : tuple of (B, S) control words from ``qr_call(export=True)``.
+    dp : BlockFPPath | PackedPath
 
     Returns
     -------
-    (B, G, mr, pw) int64 — the updated trailing region.
+    (G, mr, B, pw) — the updated trailing region.
     """
-    T, B = _pad_batch(T, tile_b)
-    flip, _ = _pad_batch(flip, tile_b)
-    sig, _ = _pad_batch(sig, tile_b)
-    Bp, G, mr, pw = T.shape
+    _check_compiles(dp, interpret)
+    B = T.shape[2]
+    T = pad_to(T, tile_b, 2)
+    words = tuple(pad_to(w, tile_b, 0) for w in words)
+    G, mr, Bp, pw = T.shape
     S = piv.shape[0]
-    grid = (Bp // tile_b, G)
-    spec = pl.BlockSpec((tile_b, 1, mr, pw), lambda b, g: (b, g, 0, 0))
-    cspec = pl.BlockSpec((tile_b, S), lambda b, g: (b, 0))
-    tspec = pl.BlockSpec((S,), lambda b, g: (0,))
-    kernel = functools.partial(_panel_apply_packed_kernel, cfg=cfg)
-    out = pl.pallas_call(
-        kernel, grid=grid,
-        in_specs=[tspec, tspec, cspec, cspec, spec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((Bp, G, mr, pw), jnp.int64),
-        interpret=interpret,
-    )(jnp.asarray(piv), jnp.asarray(tgt), flip, sig, T)
-    return out[:B]
-
-
-def _panel_factor_blockfp_kernel(piv_ref, tgt_ref, col_ref, x_ref,
-                                 o_ref, f_ref, s_ref, *, iters: int,
-                                 hub: bool, comp: int):
-    """Block-FP mirror of `_panel_factor_packed_kernel` (int32 datapath).
-
-    `fused_rotate_ctrl` runs `fused_rotate_block`'s exact vectoring
-    recurrence with the lead selected by one-hot and the (flip, sigma)
-    words exported — int32 throughout (sigma ≤ 30 bits), so this panel
-    kernel compiles wherever the flat block-FP kernel does.
-    """
-    X = x_ref[...]                       # (TB, mr, pw) int32 significands
-    pw = X.shape[-1]
-    colid = jax.lax.broadcasted_iota(jnp.int32, (1, pw), 1)
-
-    def body(X, tab):
-        piv, tgt, col = tab
-        x = X[:, piv]
-        y = X[:, tgt]
-        lead = colid == col
-        active = colid >= col
-        rx, ry, flip, sig = fused_rotate_ctrl(x, y, lead, iters=iters,
-                                              hub=hub, comp=comp)
-        rx = jnp.where(active, rx, x)    # untouched left lanes
-        ry = jnp.where(active, ry, y)
-        ry = jnp.where(lead, 0, ry)      # structural zero
-        X = X.at[:, piv].set(rx)  # lint: allow[unguarded-scatter] piv != tgt per step by schedule
-        X = X.at[:, tgt].set(ry)
-        return X, (flip, sig)
-
-    tables = (piv_ref[...], tgt_ref[...], col_ref[...])
-    X, (flips, sigs) = jax.lax.scan(body, X, tables)
-    o_ref[...] = X
-    f_ref[...] = jnp.transpose(flips)
-    s_ref[...] = jnp.transpose(sigs)
-
-
-def panel_factor_blockfp_call(X, piv, tgt, col, *, iters: int, hub: bool,
-                              interpret: bool = True, tile_b: int = TILE_B):
-    """Panel factorization over int32 block-FP significands.
-
-    Parameters as `panel_factor_packed_call` with ``X : (B, mr, pw)
-    int32`` significands (per-column shared exponents are invariant
-    under the whole rotation set, so the panel/trailing split needs no
-    re-quantization).  Returns ``(out, flip, sig)`` with (B, S) int32
-    control words.
-    """
-    X, B = _pad_batch(X, tile_b)
-    Bp, mr, pw = X.shape
-    assert iters <= 30
-    S = piv.shape[0]
-    grid = (Bp // tile_b,)
-    spec = pl.BlockSpec((tile_b, mr, pw), lambda b: (b, 0, 0))
-    cspec = pl.BlockSpec((tile_b, S), lambda b: (b, 0))
-    tspec = pl.BlockSpec((S,), lambda b: (0,))
-    kernel = functools.partial(_panel_factor_blockfp_kernel, iters=iters,
-                               hub=hub, comp=comp_q30(iters))
-    out, flip, sig = pl.pallas_call(
-        kernel, grid=grid,
-        in_specs=[tspec, tspec, tspec, spec],
-        out_specs=[spec, cspec, cspec],
-        out_shape=[jax.ShapeDtypeStruct((Bp, mr, pw), jnp.int32),
-                   jax.ShapeDtypeStruct((Bp, S), jnp.int32),
-                   jax.ShapeDtypeStruct((Bp, S), jnp.int32)],
-        interpret=interpret,
-    )(jnp.asarray(piv), jnp.asarray(tgt), jnp.asarray(col), X)
-    return out[:B], flip[:B], sig[:B]
-
-
-def _panel_apply_blockfp_kernel(piv_ref, tgt_ref, f_ref, s_ref, t_ref, o_ref,
-                                *, iters: int, hub: bool, comp: int):
-    """Block-FP mirror of `_panel_apply_packed_kernel` (`fused_replay`)."""
-    T = t_ref[...][:, 0]                 # (TB, 1, mr, pw) -> (TB, mr, pw)
-    flips = jnp.transpose(f_ref[...])
-    sigs = jnp.transpose(s_ref[...])
-
-    def body(T, tab):
-        piv, tgt, flip, sig = tab
-        rx, ry = fused_replay(T[:, piv], T[:, tgt], flip, sig,
-                              iters=iters, hub=hub, comp=comp)
-        T = T.at[:, piv].set(rx)  # lint: allow[unguarded-scatter] piv != tgt per step by schedule
-        T = T.at[:, tgt].set(ry)
-        return T, None
-
-    T, _ = jax.lax.scan(body, T, (piv_ref[...], tgt_ref[...], flips, sigs))
-    o_ref[...] = T[:, None]
-
-
-def panel_apply_blockfp_call(T, piv, tgt, flip, sig, *, iters: int,
-                             hub: bool, interpret: bool = True,
-                             tile_b: int = TILE_B):
-    """Replay exported panel controls over int32 block-FP trailing panels.
-
-    Parameters as `panel_apply_packed_call` with int32 operands.
-    """
-    T, B = _pad_batch(T, tile_b)
-    flip, _ = _pad_batch(flip, tile_b)
-    sig, _ = _pad_batch(sig, tile_b)
-    Bp, G, mr, pw = T.shape
-    assert iters <= 30
-    S = piv.shape[0]
-    grid = (Bp // tile_b, G)
-    spec = pl.BlockSpec((tile_b, 1, mr, pw), lambda b, g: (b, g, 0, 0))
-    cspec = pl.BlockSpec((tile_b, S), lambda b, g: (b, 0))
-    tspec = pl.BlockSpec((S,), lambda b, g: (0,))
-    kernel = functools.partial(_panel_apply_blockfp_kernel, iters=iters,
-                               hub=hub, comp=comp_q30(iters))
-    out = pl.pallas_call(
-        kernel, grid=grid,
-        in_specs=[tspec, tspec, cspec, cspec, spec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((Bp, G, mr, pw), jnp.int32),
-        interpret=interpret,
-    )(jnp.asarray(piv), jnp.asarray(tgt), flip, sig, T)
-    return out[:B]
+    spec = pl.BlockSpec((pl.Squeezed(), mr, tile_b, pw),
+                        lambda b, g: (g, 0, b, 0))
+    wspec = pl.BlockSpec((tile_b, S), lambda b, g: (b, 0))
+    tab_ops, tab_specs = _table_operands((piv[:, None], tgt[:, None]),
+                                         "split")
+    kernel = functools.partial(_replay_kernel, dp=dp, S=S)
+    with x32_trace(not dp.wide):
+        out = pl.pallas_call(
+            kernel, grid=(Bp // tile_b, G),
+            in_specs=[*tab_specs, *[wspec] * len(words), spec],
+            out_specs=spec,
+            out_shape=jax.ShapeDtypeStruct(T.shape, T.dtype),
+            interpret=interpret,
+        )(*tab_ops, *words, T)
+    return out[:, :, :B]

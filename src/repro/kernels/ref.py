@@ -20,10 +20,8 @@ def gain_mul_q30_ref(v, comp: int):
     c_lo = comp & 0x7FFF
     v_hi = v >> 15
     v_lo = v & 0x7FFF
-    return (v_hi * c_hi
-            + ((v_hi * c_lo) >> 15)
-            + ((v_lo * c_hi) >> 15)
-            + ((v_lo * c_lo) >> 30))
+    mid = v_hi * c_lo + v_lo * c_hi + ((v_lo * c_lo) >> 15) + (1 << 14)
+    return v_hi * c_hi + (mid >> 15)
 
 
 def _negate(v, hub):

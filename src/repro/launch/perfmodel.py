@@ -85,19 +85,17 @@ DEVICE_SPECS = {
     # dual-channel DDR-class bandwidth.  Deliberately round numbers —
     # the CPU lane is interpret-mode anyway; fractions are directional.
     "cpu": DeviceSpec("cpu", peak_ops=4.8e10, hbm_bw=2.0e10),
-    # TPU v5e: 8 VPU lanes x 8x128 x 940 MHz ~ 1e12 int32 ops/s/core,
-    # 819 GB/s HBM.
+    # TPU v5e: hbm_bw is the published 819 GB/s (Google Cloud, "TPU
+    # v5e").  peak_ops is an UNSOURCED ESTIMATE of the int32 VPU rate
+    # (8x128 lanes x ~940 MHz); no published figure backs it.
     "tpu v5 lite": DeviceSpec("tpu v5 lite", peak_ops=9.6e11, hbm_bw=8.19e11),
     "tpu v4": DeviceSpec("tpu v4", peak_ops=1.1e12, hbm_bw=1.2e12),
 }
 
-_GENERIC = DeviceSpec("generic", peak_ops=1.0e11, hbm_bw=1.0e11)
-
-
 def device_spec(kind: str | None = None) -> DeviceSpec:
     """Resolve a `DeviceSpec` for a device kind (default: this process's
-    first device).  Unknown kinds get a generic mid-range spec — the
-    fraction column stays defined, clearly labeled by spec name."""
+    first device).  A kind missing from `DEVICE_SPECS` is an error: a
+    made-up peak would give a roofline share that means nothing."""
     if kind is None:
         import jax
         kind = jax.devices()[0].device_kind
@@ -105,7 +103,8 @@ def device_spec(kind: str | None = None) -> DeviceSpec:
     for prefix, spec in DEVICE_SPECS.items():
         if k.startswith(prefix):
             return spec
-    return _GENERIC
+    raise KeyError(f"no peak figures for device kind {kind!r}; known: "
+                   f"{', '.join(DEVICE_SPECS)}")
 
 
 @dataclasses.dataclass(frozen=True)

@@ -20,29 +20,6 @@ from jax.sharding import PartitionSpec as P
 F32 = jnp.float32
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """`jax.shard_map` across JAX versions.
-
-    Newer JAX exposes `jax.shard_map(..., check_vma=...)`; 0.4.x has
-    `jax.experimental.shard_map.shard_map(..., check_rep=...)`.  Both
-    replication checks are disabled (the int8 payload intentionally
-    differs per participant before the gather).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
-def axis_size_compat(axis_name):
-    """`jax.lax.axis_size` across JAX versions (0.4.x: psum of a literal)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def quantize_int8(x):
     """Per-tensor symmetric int8 quantization. Returns (q, scale)."""
     xf = x.astype(F32)
@@ -74,7 +51,7 @@ def compressed_psum(tree, axis_name):
     Each participant quantizes its local contribution, the int8 payloads are
     all-gathered over `axis_name`, dequantized and averaged locally.
     """
-    n = axis_size_compat(axis_name)
+    n = jax.lax.axis_size(axis_name)
 
     def one(x):
         q, scale = quantize_int8(x)
@@ -100,7 +77,7 @@ def cross_pod_grad_sync(grads, residuals, mesh, enabled=True):
         q = jax.tree.map(lambda t: t[0], qs, is_leaf=lambda x: isinstance(x, tuple))
         s = jax.tree.map(lambda t: t[1], qs, is_leaf=lambda x: isinstance(x, tuple))
         new_r = jax.tree.map(lambda t: t[2], qs, is_leaf=lambda x: isinstance(x, tuple))
-        n = axis_size_compat("pod")
+        n = jax.lax.axis_size("pod")
 
         def reduce_one(qi, si):
             qg = jax.lax.all_gather(qi, "pod")
@@ -112,5 +89,7 @@ def cross_pod_grad_sync(grads, residuals, mesh, enabled=True):
         return synced, new_r
 
     spec = jax.tree.map(lambda _: P(), grads)
-    fn = shard_map_compat(inner, mesh, (spec, spec), (spec, spec))
+    # no replication check: the int8 payload differs per participant
+    fn = jax.shard_map(inner, mesh=mesh, in_specs=(spec, spec),
+                       out_specs=(spec, spec), check_vma=False)
     return fn(grads, residuals)

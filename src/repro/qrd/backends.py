@@ -114,8 +114,11 @@ def register_builtin_backends(overwrite=False):
             bit_exact=True, wavefront=True, sharding=True,
             dtypes=("float64", "complex128"),
             max_shape=(128, 128), supports_tiling=True,
+            interpret_only=("complex", "tiling"),
             description="kernel-resident unit, bit-identical to 'cordic'; "
-                        "'sameh_kuck' routes onto the wavefront datapath")),
+                        "'sameh_kuck' routes onto the wavefront datapath; "
+                        "complex and tiled words are int64 (interpret "
+                        "mode only)")),
         ("blockfp_pallas", _build_blockfp_pallas, BackendCapabilities(
             bit_exact=False, wavefront=True, sharding=True,
             max_shape=(128, 128), supports_tiling=True,

@@ -200,6 +200,28 @@ class QRDConfig:
         """
         return (self, None if self.mesh is None else id(self.mesh))
 
+    def check_compiled(self, caps, tiled: bool):
+        """Refuse what does not compile on this host's accelerator.
+
+        ``tiled`` says whether the request needs the tiled layer.  Raises
+        ``NotImplementedError`` for a complex config on a TPU, and for an
+        interpret-only feature of the backend (`BackendCapabilities.
+        interpret_only`) when its kernels would be compiled.
+        """
+        from repro.kernels import ops
+        if self.is_complex():
+            ops.check_complex_compiles(f"dtype={self.dtype!r}")
+        if ops.auto_interpret(self.interpret):
+            return
+        for feature, wanted, instead in (
+                ("complex", self.is_complex(), "the 'cordic' backend"),
+                ("tiling", tiled, "'blockfp_pallas' for tiled shapes")):
+            if wanted and feature in caps.interpret_only:
+                raise NotImplementedError(
+                    f"backend {self.backend!r} has no compiled {feature} "
+                    "datapath: its packed words are int64, which no Pallas "
+                    f"compiler lowers (interpret mode only); use {instead}")
+
     def validate(self):
         """Early validation against the registry's capability metadata."""
         from . import registry
@@ -242,6 +264,7 @@ class QRDConfig:
                 f"backend {self.backend!r} has no complex datapath "
                 f"(dtype={self.dtype!r}); complex-capable backends: "
                 f"{', '.join(registry.complex_capable_backends())}")
+        self.check_compiled(caps, self.tiling in ("panel", "tsqr"))
         if self.mesh is not None and not caps.sharding:
             capable = [n for n, c in registry.list_backends().items()
                        if c.sharding]

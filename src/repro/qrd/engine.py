@@ -194,6 +194,12 @@ class QRDEngine:
         opaque Pallas failure oversized shapes used to hit).  Note the
         TSQR route returns *economy* factors (``Q (m, n), R (n, n)``).
         """
+        fn, A = self._callable(A, compute_q, config)
+        return fn(A)
+
+    def _callable(self, A, compute_q, config=None):
+        """The jitted callable `_dispatch` runs on ``A``, and ``A`` as it
+        is handed to it (validated, cast, placed on the mesh)."""
         if config is None:
             config = self.config
         A, config = self._validate_operand(A, config)
@@ -221,7 +227,14 @@ class QRDEngine:
             work_dtype = (jnp.complex128 if config.is_complex()
                           else jnp.float64)
             A = shard_qrd_batch(jnp.asarray(A, work_dtype), config.mesh)
-        return fn(A)
+        return fn, A
+
+    def lower(self, A, compute_q=True):
+        """Ahead-of-time `jax.stages.Lowered` of the program ``engine(A)``
+        runs — ``.compile().as_text()`` shows what the device executes
+        (e.g. a ``tpu_custom_call`` per compiled Pallas kernel)."""
+        fn, A = self._callable(A, compute_q)
+        return fn.lower(A)
 
     def __call__(self, A, compute_q=True):
         """Batched QRD: ``A (..., m, n) -> (Q, R)`` (Q None w/o compute_q)."""

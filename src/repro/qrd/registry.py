@@ -77,6 +77,12 @@ class BackendCapabilities:
         The backend's kernels compose with the tiled panel/TSQR layer
         (`repro.qrd.tiled`): its rotation control words can be exported
         from a panel factorization and replayed across trailing panels.
+    interpret_only : tuple[str, ...]
+        Features (``'complex'``, ``'tiling'``) whose kernels exist only in
+        Pallas interpret mode — their words are int64, which no Pallas
+        compiler lowers.  On a compiled backend (TPU/GPU) asking for one
+        raises ``NotImplementedError`` when the engine is built (or, for
+        ``tiling='auto'``, when a shape would route onto it).
     description : str
         One line for docs and error messages.
     """
@@ -88,6 +94,7 @@ class BackendCapabilities:
     dtypes: tuple[str, ...] = ("float64",)
     max_shape: tuple[int, int] | None = None
     supports_tiling: bool = False
+    interpret_only: tuple[str, ...] = ()
     description: str = ""
 
     @property

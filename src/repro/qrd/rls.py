@@ -116,6 +116,9 @@ class RLSState:
         if dtype not in ("float64", "complex128"):
             raise ValueError(f"dtype must be 'float64' or 'complex128', "
                              f"got {dtype!r}")
+        if dtype == "complex128":
+            from repro.kernels.ops import check_complex_compiles
+            check_complex_compiles("complex RLSState")
         if mode == "block" and dtype == "complex128":
             raise TypeError("the blocked-kernel RLS path has no complex "
                             "datapath; use mode='unit' or mode='float' for "

@@ -8,11 +8,12 @@ preserving the wavefront property — every rotation is *computed once*
 ``(flip, sigma)`` control words:
 
 * **panel** — sweep the columns in ``panel_n``-wide panels.  Each panel
-  is factorized by a kernel-resident scan
-  (`repro.kernels.qrd_blocked.panel_factor_*`) that exports the control
-  words of every rotation; the trailing columns are updated by a replay
-  kernel batched over *both* the matrix batch and the trailing-panel
-  axis of the Pallas grid (`panel_apply_*`).  The panel schedule is the
+  is factorized by a kernel-resident step loop
+  (`repro.kernels.qrd_blocked.qr_call` with ``export=True``) that
+  exports the control words of every rotation; the trailing columns are
+  updated by a replay kernel (`qrd_blocked.replay_call`) batched over
+  *both* the matrix batch and the trailing-panel axis of the Pallas
+  grid.  The panel schedule is the
   column-major flat schedule split at panel boundaries — the
   concatenation of the per-panel step tables *is*
   `repro.core.qrd.givens_schedule`, so the route is bit-identical to
@@ -83,8 +84,16 @@ def resolve_route(config, m, n, caps) -> str:
     the backend's ``max_shape`` and the tiled alternative whenever the
     requested (or only available) route cannot hold the operand —
     previously such shapes died deep inside Pallas with an opaque VMEM
-    or iota-shape error.
+    or iota-shape error — and ``NotImplementedError`` when the route is
+    tiled but the backend's tiled kernels are interpret-only and would
+    be compiled (`QRDConfig.check_compiled`).
     """
+    route = _route(config, m, n, caps)
+    config.check_compiled(caps, route != "flat")
+    return route
+
+
+def _route(config, m, n, caps) -> str:
     tiling = "auto" if config.tiling is None else config.tiling
 
     # Backends without a tiled datapath (host references, float

@@ -6,8 +6,8 @@ Two guarantees from DESIGN.md §11:
   backend must produce *bit-identical* output with ``interpret=False``
   and ``interpret=True``.  On CPU-only hosts (no Mosaic/Triton target)
   these tests skip with an explicit reason rather than silently passing;
-  `benchmarks/compiled_smoke.py` is the CI entry point that runs them on
-  real accelerators.
+  ``chip_smoke.py`` at the repo root is the entry point that runs the
+  main path compiled on a TPU (and fails where it finds none).
 
 * **Ragged batches** — every ``*_call``-backed op pads the leading batch
   axis up to a multiple of ``tile_b`` and strips the padding afterward,

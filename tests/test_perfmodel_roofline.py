@@ -92,7 +92,8 @@ def test_fraction_linear_in_rate():
 def test_device_spec_prefix_match_and_fallback():
     assert pm.device_spec("TPU v5 lite").name == "tpu v5 lite"
     assert pm.device_spec("cpu").name == "cpu"
-    assert pm.device_spec("warp drive").name == "generic"
+    with pytest.raises(KeyError, match="warp drive"):
+        pm.device_spec("warp drive")
 
 
 # --------------------------------------------------------------------------

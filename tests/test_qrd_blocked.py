@@ -138,9 +138,10 @@ def test_sharded_tall_skinny_batch():
     from repro.core import qr_blocked_sharded
     from repro.launch.sharding import qrd_batch_spec
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     spec = qrd_batch_spec(3, 6, mesh)
-    assert spec[0] == ("data",) and spec[1:] == (None, None)
+    assert spec == jax.sharding.PartitionSpec(("data",), None, None)
     A = matrices((6, 8, 3), r=2.0)                     # tall-skinny batch
     unit = GivensUnit(GivensConfig(hub=True, n=26))
     _assert_bit_exact(qr_cordic(A, unit), qr_blocked_sharded(A, unit, mesh))

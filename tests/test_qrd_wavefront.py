@@ -118,7 +118,8 @@ def test_sharded_wavefront_tall_skinny_batch():
     from repro.launch.sharding import qrd_stage_table_spec
 
     assert qrd_stage_table_spec() == jax.sharding.PartitionSpec()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     A = matrices((6, 8, 3), r=2.0)               # tall-skinny batch
     unit = GivensUnit(GivensConfig(hub=True, n=26))
     ref = qr_cordic(A, unit, steps=_flat(8, 3))

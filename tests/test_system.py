@@ -95,9 +95,10 @@ import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 import repro  # enables x64
-from repro.optim.compress import compressed_psum, shard_map_compat
+from repro.optim.compress import compressed_psum
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 
 # --- compressed psum over the pod axis equals the exact mean (within int8 tol)
 x = jnp.arange(16, dtype=jnp.float32).reshape(2, 8) / 7.0
@@ -105,7 +106,8 @@ x = jnp.arange(16, dtype=jnp.float32).reshape(2, 8) / 7.0
 def f(x):
     return compressed_psum({"g": x}, "pod")["g"]
 
-out = jax.jit(shard_map_compat(f, mesh, P("pod", None), P("pod", None)))(x)
+out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("pod", None),
+                            out_specs=P("pod", None), check_vma=False))(x)
 expect = jnp.broadcast_to(jnp.mean(x, axis=0, keepdims=True), x.shape)
 err = float(jnp.max(jnp.abs(out - expect)))
 amax = float(jnp.max(jnp.abs(x)))
