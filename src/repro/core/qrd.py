@@ -242,13 +242,15 @@ def qr_blockfp_pallas(A, compute_q=True, iters=24, hub=True, frac=24,
     from repro.kernels import ops as _kops
     A = jnp.asarray(A, jnp.float64)
     m, n = A.shape[-2], A.shape[-1]
-    work = _augment(A, compute_q)
+    with jax.named_scope("encode"):
+        work = _augment(A, compute_q)
     if steps is None:
         steps = givens_schedule(m, n)
     out = _kops.givens_block_apply(work, tuple(steps), iters=iters, hub=hub,
                                    frac=frac, interpret=interpret,
                                    tile_b=tile_b)
-    return _split_qr(out, m, n, compute_q)
+    with jax.named_scope("decode"):
+        return _split_qr(out, m, n, compute_q)
 
 
 def qr_cordic_panel(A, unit: GivensUnit, compute_q=True, panel_n=8,
@@ -306,11 +308,13 @@ def qr_blockfp_panel(A, compute_q=True, iters=24, hub=True, frac=24,
     from repro.kernels import ops as _kops
     A = jnp.asarray(A, jnp.float64)
     m, n = A.shape[-2], A.shape[-1]
-    work = _augment(A, compute_q)
+    with jax.named_scope("encode"):
+        work = _augment(A, compute_q)
     out = _kops.givens_block_apply_panel(work, n_cols=n, iters=iters, hub=hub,
                                          frac=frac, panel_n=panel_n,
                                          interpret=interpret, tile_b=tile_b)
-    return _split_qr(out, m, n, compute_q)
+    with jax.named_scope("decode"):
+        return _split_qr(out, m, n, compute_q)
 
 
 # --------------------------------------------------------------------------
@@ -518,11 +522,13 @@ def qr_blockfp_wavefront(A, compute_q=True, iters=24, hub=True, frac=24,
     from repro.kernels import ops as _kops
     A = jnp.asarray(A, jnp.float64)
     m, n = A.shape[-2], A.shape[-1]
-    work = _augment(A, compute_q)
+    with jax.named_scope("encode"):
+        work = _augment(A, compute_q)
     out = _kops.givens_block_apply_wavefront(
         work, _as_stages(m, n, stages), iters=iters, hub=hub, frac=frac,
         interpret=interpret, tile_b=tile_b, table_layout=table_layout)
-    return _split_qr(out, m, n, compute_q)
+    with jax.named_scope("decode"):
+        return _split_qr(out, m, n, compute_q)
 
 
 def qr_blocked_sharded(A, unit: GivensUnit, mesh, compute_q=True,

@@ -468,15 +468,20 @@ def givens_block_apply(W, steps, *, iters=24, hub=True, frac=24,
 def _blockfp_qr(W, tables, iters, hub, frac, interpret, tile_b,
                 table_layout):
     """Shared body of the flat block-FP wrappers (in-jit): encode once,
-    run the stage tables on the int32 kernel, decode once."""
+    run the stage tables on the int32 kernel, decode once.  The codec's
+    device work carries the named scopes ``encode`` and ``decode``."""
     batch = W.shape[:-2]
     m, e = W.shape[-2:]
-    X, ex = _blockfp_encode(W.reshape((-1, m, e)), frac)
-    (out,) = qb.qr_call((_rows_first(X),), tables, qb.BlockFPPath(iters, hub),
+    with jax.named_scope("encode"):
+        X, ex = _blockfp_encode(W.reshape((-1, m, e)), frac)
+        X = _rows_first(X)
+    (out,) = qb.qr_call((X,), tables, qb.BlockFPPath(iters, hub),
                         interpret=_auto_interpret(interpret),
                         tile_b=_resolve_tile_b(tile_b),
                         table_layout=table_layout)
-    return _blockfp_decode(_rows_first(out), ex, frac).reshape(batch + (m, e))
+    with jax.named_scope("decode"):
+        out = _blockfp_decode(_rows_first(out), ex, frac)
+        return out.reshape(batch + (m, e))
 
 
 @functools.partial(jax.jit, static_argnames=("stages", "iters", "hub", "frac",
@@ -654,8 +659,11 @@ def givens_block_apply_panel(W, *, n_cols, iters=24, hub=True, frac=24,
     W = jnp.asarray(W, jnp.float64)
     batch = W.shape[:-2]
     m, e = W.shape[-2:]
-    X, ex = _blockfp_encode(W.reshape((-1, m, e)), frac)
-    X = _panel_sweep(_rows_first(X), n_cols, panel_n,
-                     qb.BlockFPPath(iters, hub), _auto_interpret(interpret),
-                     _resolve_tile_b(tile_b))
-    return _blockfp_decode(_rows_first(X), ex, frac).reshape(batch + (m, e))
+    with jax.named_scope("encode"):
+        X, ex = _blockfp_encode(W.reshape((-1, m, e)), frac)
+        X = _rows_first(X)
+    X = _panel_sweep(X, n_cols, panel_n, qb.BlockFPPath(iters, hub),
+                     _auto_interpret(interpret), _resolve_tile_b(tile_b))
+    with jax.named_scope("decode"):
+        out = _blockfp_decode(_rows_first(X), ex, frac)
+        return out.reshape(batch + (m, e))

@@ -116,11 +116,14 @@ def pad_to(x, mult, axis):
 # words replay.  Rows arrive as one tuple entry per kernel tile (the lane
 # path carries two: hi and lo), each (P, TB, e[, 2]); ``lead`` is the
 # (P, 1, e) one-hot of each pair's leading column.  `stage` returns the
-# rotated rows and the per-pair control words, (P, TB, 1) each.
+# rotated rows and the per-pair control words, (P, TB, 1) each.  `name`
+# names the datapath's kernels in a trace: ``givens_qr_<name>`` and
+# ``givens_replay_<name>``.
 # ---------------------------------------------------------------------------
 class BlockFPPath:
     """int32 block-FP significands: the fused int32 CORDIC pipeline."""
 
+    name = "blockfp"
     wide = False
     word_dtype = jnp.int32
 
@@ -140,6 +143,7 @@ class BlockFPPath:
 class PackedPath:
     """int64 packed FP words through `GivensUnit` (interpret mode only)."""
 
+    name = "packed"
     wide = True
     word_dtype = jnp.int64
 
@@ -166,6 +170,7 @@ class LanePath:
     """Packed FP words as separate (hi, lo) int32 tiles, `LaneUnit`
     arithmetic — bit-identical to `PackedPath`, and it compiles."""
 
+    name = "lanes"
     wide = False
 
     def __init__(self, cfg: GivensConfig):
@@ -195,6 +200,7 @@ class ComplexPath:
     `qr_call` like every datapath's).
     """
 
+    name = "complex"
     wide = True
 
     def __init__(self, cfg: GivensConfig):
@@ -366,6 +372,7 @@ def qr_call(tiles, tables, dp, *, interpret: bool, tile_b: int = TILE_B,
             out_specs=out_specs,
             out_shape=out_shape,
             interpret=interpret,
+            name=f"givens_qr_{dp.name}",
         )(*tab_ops, *tiles)
     outs = tuple(o[:, :B] for o in out[:len(tiles)])
     return outs + tuple(w[:B] for w in out[len(tiles):])
@@ -437,5 +444,6 @@ def replay_call(T, piv, tgt, words, dp, *, interpret: bool,
             out_specs=spec,
             out_shape=jax.ShapeDtypeStruct(T.shape, T.dtype),
             interpret=interpret,
+            name=f"givens_replay_{dp.name}",
         )(*tab_ops, *words, T)
     return out[:, :, :B]

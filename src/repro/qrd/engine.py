@@ -13,20 +13,36 @@
   Q-free augmented-column trick + `repro.qrd.solve.back_substitute`.
 * **rls** — ``engine.rls(n)``: a streaming QRD-RLS state
   (`repro.qrd.rls.RLSState`) on the backend-appropriate update path.
+
+Decompositions and solves show in a profiler trace as ``repro.qrd.*``
+spans (`repro.obs`) and programs named ``jit_qrd_<backend>``;
+`QRDEngine.stats` counts calls, matrices, builds and evictions.
 """
 from __future__ import annotations
 
+import math
 from collections import OrderedDict
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels import autotune
+from repro.obs import span
 
 from .config import QRDConfig
 from .solve import lstsq_from_triangular
 
 __all__ = ["QRDEngine"]
+
+
+def _named(body, name):
+    """``body`` as a function called ``name``: `jax.jit` names its
+    program ``jit_<name>``, so traces show the engine's programs by a
+    stable name instead of the builder's lambda."""
+    def program(A):
+        return body(A)
+    program.__name__ = program.__qualname__ = name
+    return program
 
 
 class QRDEngine:
@@ -69,6 +85,7 @@ class QRDEngine:
             raise ValueError("max_cache must be >= 1")
         self._max_cache = int(max_cache)
         self._fn_cache: OrderedDict = OrderedDict()
+        self._calls = self._matrices = self._builds = self._evictions = 0
 
     # -- introspection --------------------------------------------------------
     @property
@@ -193,13 +210,28 @@ class QRDEngine:
         alternatives when no route can hold the operand (instead of the
         opaque Pallas failure oversized shapes used to hit).  Note the
         TSQR route returns *economy* factors (``Q (m, n), R (n, n)``).
-        """
-        fn, A = self._callable(A, compute_q, config)
-        return fn(A)
 
-    def _callable(self, A, compute_q, config=None):
-        """The jitted callable `_dispatch` runs on ``A``, and ``A`` as it
-        is handed to it (validated, cast, placed on the mesh)."""
+        Each call is one ``repro.qrd.call`` span holding ``prepare`` and
+        then ``launch`` (or ``build`` on an LRU miss); see `repro.obs`.
+        """
+        self._calls += 1
+        with span("repro.qrd.call", call=self._calls) as call:
+            with span("repro.qrd.prepare"):
+                A, config, key = self._prepare(A, compute_q, config)
+                fn = self._lookup(key)
+                batch = math.prod(A.shape[:-2])
+                self._matrices += batch
+                call.set_metadata(m=key[0], n=key[1], batch=batch,
+                                  backend=config.backend)
+            if fn is None:
+                with span("repro.qrd.build"):
+                    return self._build(key, config)(A)
+            with span("repro.qrd.launch"):
+                return fn(A)
+
+    def _prepare(self, A, compute_q, config=None):
+        """``A`` as the program takes it (validated, cast, placed on the
+        mesh), the routing config and the LRU key."""
         if config is None:
             config = self.config
         A, config = self._validate_operand(A, config)
@@ -208,33 +240,63 @@ class QRDEngine:
         m, n = A.shape[-2], A.shape[-1]
         config = self._resolve_tuned(config, m, n)
         key = (m, n, bool(compute_q), config.cache_key())
-        fn = self._fn_cache.pop(key, None)
-        if fn is None:
-            from . import tiled
-            spec = config.validate()
-            route = tiled.resolve_route(config, m, n, spec.capabilities)
-            if route == "flat":
-                fn = jax.jit(spec.builder(config, m, n, bool(compute_q)))
-            else:
-                fn = jax.jit(tiled.build_tiled(route, config, m, n,
-                                               bool(compute_q),
-                                               spec.capabilities))
-        self._fn_cache[key] = fn           # (re-)insert as most-recent
-        while len(self._fn_cache) > self._max_cache:
-            self._fn_cache.popitem(last=False)
         if config.mesh is not None:
             from repro.launch.sharding import shard_qrd_batch
             work_dtype = (jnp.complex128 if config.is_complex()
                           else jnp.float64)
             A = shard_qrd_batch(jnp.asarray(A, work_dtype), config.mesh)
-        return fn, A
+        return A, config, key
+
+    def _lookup(self, key):
+        """The cached callable for ``key``, made most-recent; None on a
+        miss."""
+        fn = self._fn_cache.pop(key, None)
+        if fn is not None:
+            self._fn_cache[key] = fn
+        return fn
+
+    def _build(self, key, config):
+        """Build, cache and return the jitted program for ``key``,
+        evicting the least-recently used beyond ``max_cache``."""
+        from . import tiled
+        m, n, compute_q, _ = key
+        spec = config.validate()
+        route = tiled.resolve_route(config, m, n, spec.capabilities)
+        if route == "flat":
+            body = spec.builder(config, m, n, compute_q)
+            name = f"qrd_{config.backend}"
+        else:
+            body = tiled.build_tiled(route, config, m, n, compute_q,
+                                     spec.capabilities)
+            name = f"qrd_{config.backend}_{route}"
+        fn = jax.jit(_named(body, name))
+        self._builds += 1
+        self._fn_cache[key] = fn
+        while len(self._fn_cache) > self._max_cache:
+            self._fn_cache.popitem(last=False)
+            self._evictions += 1
+        return fn
 
     def lower(self, A, compute_q=True):
         """Ahead-of-time `jax.stages.Lowered` of the program ``engine(A)``
         runs — ``.compile().as_text()`` shows what the device executes
         (e.g. a ``tpu_custom_call`` per compiled Pallas kernel)."""
-        fn, A = self._callable(A, compute_q)
+        A, config, key = self._prepare(A, compute_q)
+        fn = self._lookup(key) or self._build(key, config)
         return fn.lower(A)
+
+    def stats(self) -> dict:
+        """Counters since construction: ``calls`` (decompositions and
+        solves), ``matrices`` (summed over their batches), ``builds``
+        (LRU misses, each one program traced and compiled or loaded) and
+        ``evictions`` (programs dropped beyond ``max_cache``).
+
+        In steady state ``builds`` and ``evictions`` stay flat; if they
+        keep rising, ``max_cache`` is smaller than the set of shapes and
+        configs in use, or callers churn shapes, and calls pay a compile.
+        """
+        return {"calls": self._calls, "matrices": self._matrices,
+                "builds": self._builds, "evictions": self._evictions}
 
     def __call__(self, A, compute_q=True):
         """Batched QRD: ``A (..., m, n) -> (Q, R)`` (Q None w/o compute_q)."""
