@@ -192,9 +192,9 @@ def qr_cordic_pallas(A, unit: GivensUnit, compute_q=True, steps=None,
     interpret : bool, optional
         Forwarded to the kernel; None auto-selects (interpret on CPU).
     tile_b : int, optional
-        Batch tile of the blocked kernel; None takes the default
-        (``TILE_B``, or the engine's autotuned value when dispatched
-        through `repro.qrd.QRDEngine`).
+        Batch tile of the blocked kernel; None lets the kernel choose
+        from the shape (or takes the engine's autotuned value when
+        dispatched through `repro.qrd.QRDEngine`).
 
     Returns
     -------
@@ -463,7 +463,7 @@ def qr_cordic_wavefront(A, unit: GivensUnit, compute_q=True, stages=None,
 
     The stage-parallel counterpart of `qr_cordic_pallas` (DESIGN.md §8):
     all rotations of a stage — their row pairs are disjoint by construction
-    — run in one shot along a (TILE_B, Pmax, e) pair axis, so the
+    — run in one shot along a pair axis of the resident tile, so the
     sequential depth collapses from ``len(steps)`` dependent rotations to
     ``len(stages)`` loop iterations, and the trace holds one stage body
     instead of the whole unrolled schedule.  (Q, R) are bit-identical to

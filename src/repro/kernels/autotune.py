@@ -6,7 +6,9 @@ problem shape or device.  This module searches the small discrete space
 that actually matters for these kernels —
 
 * ``tile_b``  — how many matrices ride in one kernel instance's VMEM
-  block (powers of two up to the batch, capped by a VMEM budget model);
+  block (powers of two up to the batch, capped by the kernels' VMEM
+  budget model, `qrd_blocked.tile_vmem_bytes`; whole lane rows of 128
+  matrices where the shape runs batch-minor);
 * ``table_layout`` — ``'split'`` (three (S, Pmax) stage-table operands)
   vs ``'stacked'`` (one concatenated (3S, Pmax) operand) for the
   wavefront kernels
@@ -39,22 +41,14 @@ import time
 
 import numpy as np
 
+from . import qrd_blocked as qb
+
 __all__ = ["TuneEntry", "TUNABLE_BACKENDS", "cache_path", "device_kind",
            "cache_key", "lookup", "tune", "tune_tiled",
            "candidate_tile_bs", "candidate_layouts", "candidate_panel_ns",
            "candidate_tile_ms", "clear_memo"]
 
 TUNABLE_BACKENDS = ("cordic_pallas", "blockfp_pallas")
-
-#: Default VMEM budget (bytes) for the tile model — deliberately modest
-#: (a TPU core has ~16 MiB but the working tile shares it with stage
-#: tables, semaphores, and double-buffering headroom).
-DEFAULT_VMEM_BUDGET = 2 * 1024 * 1024
-
-#: Buffers the VMEM model charges per resident element: input block +
-#: output block + roughly four working copies live across a rotation
-#: step (x/y gathers, rotated halves, scatter temporaries).
-_VMEM_BUFFERS = 6
 
 _SCHEMA_VERSION = 1
 
@@ -189,27 +183,36 @@ def lookup(backend: str, schedule: str, m: int, n: int, dtype: str,
 # Candidate generation
 # --------------------------------------------------------------------------
 def candidate_tile_bs(batch: int, m: int, e: int, itemsize: int,
-                      vmem_budget: int | None = None) -> tuple:
-    """Power-of-two batch tiles that fit the VMEM budget model.
+                      vmem_budget: int | None = None,
+                      batch_minor: bool | None = None) -> tuple:
+    """Batch tiles that fit the VMEM budget model.
 
-    The model charges ``_VMEM_BUFFERS`` resident copies of the
-    (tile_b, m, e) working block at ``itemsize`` bytes per element
-    against the budget (``$REPRO_TILE_VMEM_BUDGET`` or
-    `DEFAULT_VMEM_BUDGET`).  Candidates are capped at
-    ``min(batch, 64)``; the smallest power of two always survives so the
-    search space is never empty.
+    The model (`qrd_blocked.tile_vmem_bytes`) charges
+    ``qrd_blocked.VMEM_BUFFERS`` resident copies of the (tile_b, m, e)
+    working block at ``itemsize`` bytes per element against the budget
+    (``$REPRO_TILE_VMEM_BUDGET`` or ``qrd_blocked.DEFAULT_VMEM_BUDGET``).
+    Rows-first, candidates are powers of two capped at
+    ``min(batch, 64)``; batch-minor (``batch_minor=None`` asks the
+    kernels' rule, `qrd_blocked.batch_minor`), whole lane rows — 128
+    times a power of two, up to the batch rounded up to a lane row.
+    The smallest candidate always survives so the search space is never
+    empty.
     """
     if vmem_budget is None:
         vmem_budget = int(os.environ.get("REPRO_TILE_VMEM_BUDGET",
-                                         DEFAULT_VMEM_BUDGET))
-    cap = max(1, min(int(batch), 64))
+                                         qb.DEFAULT_VMEM_BUDGET))
+    if batch_minor is None:
+        batch_minor = qb.batch_minor(m, e, batch, itemsize)
+    unit = qb.LANES if batch_minor else 1
+    cap = (-(-int(batch) // unit) * unit if batch_minor
+           else max(1, min(int(batch), 64)))
     cands = []
-    tb = 1
+    tb = unit
     while tb <= cap:
         cands.append(tb)
         tb *= 2
-    bytes_per = _VMEM_BUFFERS * m * e * itemsize
-    fit = [tb for tb in cands if tb * bytes_per <= vmem_budget]
+    fit = [tb for tb in cands
+           if qb.tile_vmem_bytes(m, e, itemsize, tb) <= vmem_budget]
     return tuple(fit) if fit else (cands[0],)
 
 
@@ -292,10 +295,14 @@ def tune(backend: str, schedule: str, m: int, n: int, batch: int, *,
 
     # Working-element size of the kernel-resident block: the packed
     # cordic word is 8 bytes (int64, or the dual-int32 lane pair); the
-    # block-FP path holds int32 significands.
+    # block-FP path holds int32 significands.  Interpreted, the cordic
+    # words are int64 and run rows-first.
+    from .ops import auto_interpret
     itemsize = 8 if backend == "cordic_pallas" else 4
     e = n + (m if compute_q else 0)
-    tiles = candidate_tile_bs(batch, m, e, itemsize, vmem_budget)
+    rows_first = backend == "cordic_pallas" and auto_interpret(None)
+    tiles = candidate_tile_bs(batch, m, e, itemsize, vmem_budget,
+                              batch_minor=False if rows_first else None)
     layouts = candidate_layouts(schedule)
 
     kwargs = {} if givens is None else {"givens": givens}

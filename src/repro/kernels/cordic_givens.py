@@ -250,17 +250,19 @@ def fused_rotate_block(x, y, *, iters: int, hub: bool, comp: int):
     return fused_replay(x, y, flip, sig, iters=iters, hub=hub, comp=comp)
 
 
-def fused_rotate_pairs(x, y, lead, *, iters: int, hub: bool, comp: int):
+def fused_rotate_pairs(x, y, lead, *, iters: int, hub: bool, comp: int,
+                       axis: int = -1):
     """Fused Givens step on a whole *pair axis* of resident row blocks.
 
     The kernel-resident building block of every blocked QR kernel
-    (DESIGN.md §8, §14): ``x``/``y`` are (P, TB, e) pivot/target rows at
-    *uniform* width e, and ``lead`` is the (P, 1, e) 0/1 one-hot of each
-    pair's leading column (the annihilated entry's column).  The leading
-    pair is extracted by the one-hot contraction, vectoring derives one
-    (flip, sigma) control word per (pair, batch) lane, and the replay
-    broadcasts it across the whole e axis — every pair rotates in one
-    shot.
+    (DESIGN.md §8, §14): ``x``/``y`` are pivot/target rows at *uniform*
+    width e along the element ``axis`` — (P, TB, e) rows-first, or
+    (P, e, rows, 128) batch-minor — and ``lead`` is the 0/1 one-hot of
+    each pair's leading column (the annihilated entry's column) along
+    it, (P, 1, e) or (P, e, rows, 128).  The leading pair is extracted by the
+    one-hot contraction, vectoring derives one (flip, sigma) control
+    word per (pair, matrix), and the replay broadcasts it across the
+    whole e axis — every pair rotates in one shot.
 
     Column masking is the caller's job: lanes left of the leading column
     are rotated here too (uniform shape keeps the datapath wide) and must
@@ -272,14 +274,15 @@ def fused_rotate_pairs(x, y, lead, *, iters: int, hub: bool, comp: int):
     at and right of `lead` match `fused_rotate_block` on the ragged slice
     exactly.
 
-    Returns ``(rx, ry, flip, sig)`` — the rotated rows plus the (P, TB, 1)
-    int32 control words (flip as 0/1), which the panel kernels export for
-    replay over trailing panels (`fused_replay`).
+    Returns ``(rx, ry, flip, sig)`` — the rotated rows plus the int32
+    control words (flip as 0/1), shaped like the rows with ``axis`` of
+    size 1, which the panel kernels export for replay over trailing
+    panels (`fused_replay`).
     """
     sel = lead.astype(x.dtype)
     # dtype-pinned sums: default integer accumulation widens to int64
-    xl = jnp.sum(x * sel, axis=-1, keepdims=True, dtype=x.dtype)
-    yl = jnp.sum(y * sel, axis=-1, keepdims=True, dtype=y.dtype)
+    xl = jnp.sum(x * sel, axis=axis, keepdims=True, dtype=x.dtype)
+    yl = jnp.sum(y * sel, axis=axis, keepdims=True, dtype=y.dtype)
     flip, sig = _vector_lead(xl, yl, iters=iters, hub=hub)
     rx, ry = fused_replay(x, y, flip, sig, iters=iters, hub=hub, comp=comp)
     return rx, ry, flip.astype(jnp.int32), sig

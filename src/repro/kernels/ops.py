@@ -12,8 +12,12 @@ automatically reroute onto the dual-int32 lane kernels
 (`qrd_blocked.LanePath`) — Mosaic/Triton reject int64 lanes;
 the split is bit-exact (`lanes=None`/`True`/`False` overrides).
 
-``tile_b=None`` resolves to the fixed `TILE_B` here; shape-tuned values
-come from `repro.kernels.autotune` via `repro.qrd.engine` (DESIGN.md §11).
+The flat block-FP and lane wrappers pick the kernel's layout from the
+shape (`qrd_blocked.batch_minor`): batch-minor for many small matrices,
+rows-first otherwise; the encode transposes once into it and the decode
+back.  ``tile_b=None`` lets the kernel choose (`TILE_B` rows-first, up to
+1024 matrices batch-minor); shape-tuned values come from
+`repro.kernels.autotune` via `repro.qrd.engine` (DESIGN.md §11).
 """
 from __future__ import annotations
 
@@ -98,11 +102,6 @@ def auto_interpret(interpret=None) -> bool:
 _auto_interpret = auto_interpret
 
 
-def _resolve_tile_b(tile_b):
-    """``tile_b=None`` -> the fixed default; tuned values come from callers."""
-    return qb.TILE_B if tile_b is None else tile_b
-
-
 def _resolve_layout(table_layout):
     return "split" if table_layout is None else table_layout
 
@@ -110,6 +109,16 @@ def _resolve_layout(table_layout):
 def _rows_first(X):
     """(B, m, ...) <-> (m, B, ...): the kernels' rows-first layout."""
     return jnp.swapaxes(X, 0, 1)
+
+
+def _to_kernel(X, batch_minor):
+    """(B, m, e) -> the kernel's layout: (m, e, B) or rows-first."""
+    return jnp.moveaxis(X, 0, -1) if batch_minor else _rows_first(X)
+
+
+def _from_kernel(X, batch_minor):
+    """The kernel's layout -> (B, m, e); inverts `_to_kernel`."""
+    return jnp.moveaxis(X, -1, 0) if batch_minor else _rows_first(X)
 
 
 @functools.partial(jax.jit, static_argnames=("iters", "hub", "interpret"))
@@ -239,17 +248,19 @@ def _packed_qr(P, tables, cfg, interpret, tile_b, lanes, table_layout):
     lanes = (not interpret) if lanes is None else lanes
     batch = P.shape[:-2]
     m, e = P.shape[-2:]
-    Pf = _rows_first(P.astype(jnp.int64).reshape((-1, m, e)))
-    kw = dict(interpret=interpret, tile_b=_resolve_tile_b(tile_b),
-              table_layout=table_layout)
+    Pf = P.astype(jnp.int64).reshape((-1, m, e))
+    dp = qb.LanePath(cfg) if lanes else qb.PackedPath(cfg)
+    bm = not dp.wide and qb.batch_minor(m, e, Pf.shape[0], dp.itemsize)
+    Pf = _to_kernel(Pf, bm)
+    kw = dict(interpret=interpret, tile_b=tile_b, table_layout=table_layout,
+              batch_minor=bm)
     if lanes:
         L = k.packed_to_lanes(Pf)
-        hi, lo = qb.qr_call((L[..., 0], L[..., 1]), tables,
-                            qb.LanePath(cfg), **kw)
+        hi, lo = qb.qr_call((L[..., 0], L[..., 1]), tables, dp, **kw)
         out = k.lanes_to_packed(jnp.stack([hi, lo], axis=-1))
     else:
-        (out,) = qb.qr_call((Pf,), tables, qb.PackedPath(cfg), **kw)
-    return _rows_first(out).reshape(batch + (m, e))
+        (out,) = qb.qr_call((Pf,), tables, dp, **kw)
+    return _from_kernel(out, bm).reshape(batch + (m, e))
 
 
 @functools.partial(jax.jit,
@@ -295,7 +306,7 @@ def _complex_qr(P, tables, cfg, interpret, tile_b, table_layout):
     m, e, _ = P.shape[-3:]
     Pf = _rows_first(P.astype(jnp.int64).reshape((-1, m, e, 2)))
     (out,) = qb.qr_call((Pf,), tables, qb.ComplexPath(cfg), interpret=True,
-                        tile_b=_resolve_tile_b(tile_b),
+                        tile_b=tile_b,
                         table_layout=table_layout)
     return _rows_first(out).reshape(batch + (m, e, 2))
 
@@ -467,20 +478,23 @@ def givens_block_apply(W, steps, *, iters=24, hub=True, frac=24,
 
 def _blockfp_qr(W, tables, iters, hub, frac, interpret, tile_b,
                 table_layout):
-    """Shared body of the flat block-FP wrappers (in-jit): encode once,
-    run the stage tables on the int32 kernel, decode once.  The codec's
-    device work carries the named scopes ``encode`` and ``decode``."""
+    """Shared body of the flat block-FP wrappers (in-jit): encode once
+    into the kernel's layout, run the stage tables on the int32 kernel,
+    decode once.  The codec's device work carries the named scopes
+    ``encode`` and ``decode``."""
     batch = W.shape[:-2]
     m, e = W.shape[-2:]
+    W = W.reshape((-1, m, e))
+    dp = qb.BlockFPPath(iters, hub)
+    bm = qb.batch_minor(m, e, W.shape[0], dp.itemsize)
     with jax.named_scope("encode"):
-        X, ex = _blockfp_encode(W.reshape((-1, m, e)), frac)
-        X = _rows_first(X)
-    (out,) = qb.qr_call((X,), tables, qb.BlockFPPath(iters, hub),
-                        interpret=_auto_interpret(interpret),
-                        tile_b=_resolve_tile_b(tile_b),
-                        table_layout=table_layout)
+        X, ex = _blockfp_encode(W, frac)
+        X = _to_kernel(X, bm)
+    (out,) = qb.qr_call((X,), tables, dp,
+                        interpret=_auto_interpret(interpret), tile_b=tile_b,
+                        table_layout=table_layout, batch_minor=bm)
     with jax.named_scope("decode"):
-        out = _blockfp_decode(_rows_first(out), ex, frac)
+        out = _blockfp_decode(_from_kernel(out, bm), ex, frac)
         return out.reshape(batch + (m, e))
 
 
@@ -494,8 +508,8 @@ def givens_block_apply_wavefront(W, stages, *, iters=24, hub=True, frac=24,
 
     Identical quantize-once / decode-once block-FP dataflow, but the step
     schedule is replaced by Sameh–Kuck stage index tables: one scan
-    iteration rotates every disjoint row pair of a stage along a
-    (TILE_B, Pmax, e) pair axis (DESIGN.md §8).  Bit-identical to
+    iteration rotates every disjoint row pair of a stage along a pair
+    axis of the resident tile (DESIGN.md §8).  Bit-identical to
     `givens_block_apply` on the flattened stage schedule.
 
     Parameters
@@ -627,7 +641,7 @@ def qr_packed_panel(P, *, cfg, n_cols, panel_n=8, interpret=None,
     m, e = P.shape[-2:]
     Pf = _rows_first(P.astype(jnp.int64).reshape((-1, m, e)))
     Pf = _panel_sweep(Pf, n_cols, panel_n, qb.PackedPath(cfg), interpret,
-                      _resolve_tile_b(tile_b))
+                      tile_b)
     return _rows_first(Pf).reshape(batch + (m, e))
 
 
@@ -663,7 +677,7 @@ def givens_block_apply_panel(W, *, n_cols, iters=24, hub=True, frac=24,
         X, ex = _blockfp_encode(W.reshape((-1, m, e)), frac)
         X = _rows_first(X)
     X = _panel_sweep(X, n_cols, panel_n, qb.BlockFPPath(iters, hub),
-                     _auto_interpret(interpret), _resolve_tile_b(tile_b))
+                     _auto_interpret(interpret), tile_b)
     with jax.named_scope("decode"):
         out = _blockfp_decode(_rows_first(X), ex, frac)
         return out.reshape(batch + (m, e))

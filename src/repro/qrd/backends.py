@@ -18,7 +18,7 @@ from repro.core.givens import GivensUnit
 from .registry import (BackendCapabilities, available_backends,
                        register_backend)
 
-__all__ = ["register_builtin_backends"]
+__all__ = ["register_builtin_backends", "batch_padding"]
 
 
 def _flat_steps(config, m, n):
@@ -87,6 +87,32 @@ def _build_blockfp_pallas(config, m, n, compute_q):
 def _build_fixed(config, m, n, compute_q):
     return lambda A: _q.qr_fixed(A, config.fixed_width, config.fixed_iters,
                                  config.fixed_scale_exp, compute_q=compute_q)
+
+
+def batch_padding(config, m, n, compute_q, batch) -> int:
+    """Zero matrices the flat Pallas kernel of ``config`` adds to a batch
+    of ``batch`` m×n operands to fill its batch tiles.
+
+    The kernel's layout follows from the shape as in `repro.kernels.ops`
+    (`qrd_blocked.batch_minor`).  Other backends, and the tiled routes,
+    which re-tile per panel and per tree level, count 0.
+    """
+    from repro.kernels import ops, qrd_blocked as qb
+    from . import registry, tiled
+
+    if config.backend == "blockfp_pallas":
+        itemsize, wide = qb.BlockFPPath.itemsize, False
+    elif config.backend == "cordic_pallas":    # int64 words interpreted
+        itemsize = qb.LanePath.itemsize
+        wide = config.is_complex() or ops.auto_interpret(config.interpret)
+    else:
+        return 0
+    caps = registry.get_backend(config.backend).capabilities
+    if tiled.resolve_route(config, m, n, caps) != "flat":
+        return 0
+    e = n + m if compute_q else n
+    bm = not wide and qb.batch_minor(m, e, batch, itemsize)
+    return qb.padded_batch(batch, config.tile_b, bm) - batch
 
 
 def register_builtin_backends(overwrite=False):

@@ -62,10 +62,13 @@ class QRDConfig:
         Forwarded to the Pallas kernels; ``None`` auto-selects
         (interpret on CPU, Mosaic on TPU).
     tile_b : int, optional
-        Batch tile of the blocked Pallas kernels.  ``None`` consults the
-        persisted autotune cache (`repro.kernels.autotune.lookup`) at
-        dispatch time and falls back to the fixed ``TILE_B`` default on a
-        cache miss; an explicit value always wins.
+        Batch tile of the blocked Pallas kernels (matrices per grid
+        cell).  ``None`` consults the persisted autotune cache
+        (`repro.kernels.autotune.lookup`) at dispatch time and lets the
+        kernel choose on a cache miss (``TILE_B`` rows-first, up to 1024
+        matrices batch-minor, DESIGN.md §5); an explicit value always
+        wins, rounded up to whole lane rows of 128 where the shape runs
+        batch-minor.
     table_layout : str, optional
         Stage-table memory layout of the wavefront kernels: ``'split'``
         (three separate (S, Pmax) operands) or ``'stacked'`` (one

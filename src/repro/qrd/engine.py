@@ -16,7 +16,8 @@
 
 Decompositions and solves show in a profiler trace as ``repro.qrd.*``
 spans (`repro.obs`) and programs named ``jit_qrd_<backend>``;
-`QRDEngine.stats` counts calls, matrices, builds and evictions.
+`QRDEngine.stats` counts calls, matrices, padded matrices, builds and
+evictions.
 """
 from __future__ import annotations
 
@@ -86,6 +87,8 @@ class QRDEngine:
         self._max_cache = int(max_cache)
         self._fn_cache: OrderedDict = OrderedDict()
         self._calls = self._matrices = self._builds = self._evictions = 0
+        self._padded = 0
+        self._pads: dict = {}     # (LRU key, batch) -> padded matrices
 
     # -- introspection --------------------------------------------------------
     @property
@@ -221,6 +224,7 @@ class QRDEngine:
                 fn = self._lookup(key)
                 batch = math.prod(A.shape[:-2])
                 self._matrices += batch
+                self._padded += self._padding(key, config, batch)
                 call.set_metadata(m=key[0], n=key[1], batch=batch,
                                   backend=config.backend)
             if fn is None:
@@ -246,6 +250,17 @@ class QRDEngine:
                           else jnp.float64)
             A = shard_qrd_batch(jnp.asarray(A, work_dtype), config.mesh)
         return A, config, key
+
+    def _padding(self, key, config, batch):
+        """Zero matrices the program for ``key`` adds to a batch of
+        ``batch`` to fill its kernel's batch tiles: worked out once per
+        program and batch (`backends.batch_padding`), then looked up."""
+        pad = self._pads.get((key, batch))
+        if pad is None:
+            from .backends import batch_padding
+            pad = self._pads[(key, batch)] = batch_padding(
+                config, key[0], key[1], key[2], batch)
+        return pad
 
     def _lookup(self, key):
         """The cached callable for ``key``, made most-recent; None on a
@@ -273,7 +288,9 @@ class QRDEngine:
         self._builds += 1
         self._fn_cache[key] = fn
         while len(self._fn_cache) > self._max_cache:
-            self._fn_cache.popitem(last=False)
+            old, _ = self._fn_cache.popitem(last=False)
+            for k in [k for k in self._pads if k[0] == old]:
+                del self._pads[k]
             self._evictions += 1
         return fn
 
@@ -287,8 +304,10 @@ class QRDEngine:
 
     def stats(self) -> dict:
         """Counters since construction: ``calls`` (decompositions and
-        solves), ``matrices`` (summed over their batches), ``builds``
-        (LRU misses, each one program traced and compiled or loaded) and
+        solves), ``matrices`` (summed over their batches),
+        ``padded_matrices`` (zero matrices the flat Pallas kernels added
+        to fill their batch tiles, summed over calls), ``builds`` (LRU
+        misses, each one program traced and compiled or loaded) and
         ``evictions`` (programs dropped beyond ``max_cache``).
 
         In steady state ``builds`` and ``evictions`` stay flat; if they
@@ -296,7 +315,8 @@ class QRDEngine:
         configs in use, or callers churn shapes, and calls pay a compile.
         """
         return {"calls": self._calls, "matrices": self._matrices,
-                "builds": self._builds, "evictions": self._evictions}
+                "padded_matrices": self._padded, "builds": self._builds,
+                "evictions": self._evictions}
 
     def __call__(self, A, compute_q=True):
         """Batched QRD: ``A (..., m, n) -> (Q, R)`` (Q None w/o compute_q)."""

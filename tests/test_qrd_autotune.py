@@ -44,8 +44,11 @@ def test_candidates_are_powers_of_two_capped_by_batch():
     cands = autotune.candidate_tile_bs(24, 4, 8, 8,
                                        vmem_budget=1 << 30)
     assert cands == (1, 2, 4, 8, 16)          # <= batch, powers of two
+    assert autotune.candidate_tile_bs(256, 4, 8, 8, vmem_budget=1 << 30,
+                                      batch_minor=False)[-1] == 64
+    # 256 4x4 matrices with Q run batch-minor: whole lane rows
     assert autotune.candidate_tile_bs(256, 4, 8, 8,
-                                      vmem_budget=1 << 30)[-1] == 64
+                                      vmem_budget=1 << 30) == (128, 256)
 
 
 def test_candidates_respect_vmem_budget():

@@ -16,18 +16,22 @@ RNG = np.random.default_rng(13)
 
 def test_stats_count_calls_matrices_builds_and_evictions():
     eng = api.QRDEngine(backend="jnp", max_cache=2)
-    assert eng.stats() == {"calls": 0, "matrices": 0, "builds": 0,
+    assert eng.stats() == {"calls": 0, "matrices": 0,
+                           "padded_matrices": 0, "builds": 0,
                            "evictions": 0}
     eng(RNG.normal(size=(3, 4, 4)))
     eng(RNG.normal(size=(3, 4, 4)))              # same shape: LRU hit
-    assert eng.stats() == {"calls": 2, "matrices": 6, "builds": 1,
+    assert eng.stats() == {"calls": 2, "matrices": 6,
+                           "padded_matrices": 0, "builds": 1,
                            "evictions": 0}
     eng(RNG.normal(size=(2, 5, 4, 2)))           # new shape, batch 2 x 5
     eng(RNG.normal(size=(4, 3)))                 # one matrix: evicts 4x4
-    assert eng.stats() == {"calls": 4, "matrices": 17, "builds": 3,
+    assert eng.stats() == {"calls": 4, "matrices": 17,
+                           "padded_matrices": 0, "builds": 3,
                            "evictions": 1}
     eng.solve(RNG.normal(size=(2, 6, 3)), RNG.normal(size=(2, 6)))
-    assert eng.stats() == {"calls": 5, "matrices": 19, "builds": 4,
+    assert eng.stats() == {"calls": 5, "matrices": 19,
+                           "padded_matrices": 0, "builds": 4,
                            "evictions": 2}
     assert len(eng._fn_cache) == 2
 
@@ -36,7 +40,8 @@ def test_lower_builds_without_counting_a_call():
     eng = api.QRDEngine(backend="jnp")
     eng.lower(RNG.normal(size=(2, 4, 4)))
     eng(RNG.normal(size=(2, 4, 4)))              # the program lower() built
-    assert eng.stats() == {"calls": 1, "matrices": 2, "builds": 1,
+    assert eng.stats() == {"calls": 1, "matrices": 2,
+                           "padded_matrices": 0, "builds": 1,
                            "evictions": 0}
 
 

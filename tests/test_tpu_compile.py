@@ -42,10 +42,17 @@ def _compile(fn, *shapes):
     assert "tpu_custom_call" in text
 
 
-def _flat(tables, dp, export=False):
+def _flat(tables, dp, export=False, batch_minor=False):
     def fn(*tiles):
-        return qb.qr_call(tiles, tables, dp, interpret=False, export=export)
+        return qb.qr_call(tiles, tables, dp, interpret=False, export=export,
+                          batch_minor=batch_minor)
     return fn
+
+
+def _tables(schedule, m=8, n=8):
+    if schedule == "col":
+        return ops.step_tables(q.givens_schedule(m, n), m)
+    return ops.stage_tables(q.sameh_kuck_schedule(m, n), m)
 
 
 def test_fused_rotator(one_chip):
@@ -56,18 +63,36 @@ def test_fused_rotator(one_chip):
 
 @pytest.mark.parametrize("schedule", ["col", "sameh_kuck"])
 def test_blockfp_8x8(one_chip, schedule):
-    if schedule == "col":
-        tables = ops.step_tables(q.givens_schedule(8, 8), 8)
-    else:
-        tables = ops.stage_tables(q.sameh_kuck_schedule(8, 8), 8)
-    X = jax.ShapeDtypeStruct((8, B, 16), jnp.int32, sharding=one_chip)
-    _compile(_flat(tables, qb.BlockFPPath(24, True)), X)
+    assert qb.batch_minor(8, 16, B, qb.BlockFPPath.itemsize)
+    X = jax.ShapeDtypeStruct((8, 16, B), jnp.int32, sharding=one_chip)
+    _compile(_flat(_tables(schedule), qb.BlockFPPath(24, True),
+                   batch_minor=True), X)
 
 
 def test_lanes_col_8x8(one_chip):
-    tables = ops.step_tables(q.givens_schedule(8, 8), 8)
-    half = jax.ShapeDtypeStruct((8, B, 16), jnp.int32, sharding=one_chip)
-    _compile(_flat(tables, qb.LanePath(GivensConfig(hub=True))), half, half)
+    assert qb.batch_minor(8, 16, B, qb.LanePath.itemsize)
+    half = jax.ShapeDtypeStruct((8, 16, B), jnp.int32, sharding=one_chip)
+    _compile(_flat(_tables("col"), qb.LanePath(GivensConfig(hub=True)),
+                   batch_minor=True), half, half)
+
+
+@pytest.mark.parametrize("batch", [3276, 273], ids=["slot", "prb"])
+@pytest.mark.parametrize("path", ["blockfp-col", "blockfp-sameh_kuck",
+                                  "lanes-col"])
+def test_batch_minor_cells(one_chip, path, batch):
+    """The batch-minor kernel at the MIMO cells' shapes: 8×8 with Q, a
+    slot's 3276 matrices (four cells of seven lane rows) and a PRB
+    call's 273 (one cell of three), named for its layout."""
+    datapath, schedule = path.split("-")
+    dp = (qb.BlockFPPath(24, True) if datapath == "blockfp"
+          else qb.LanePath(GivensConfig(hub=True)))
+    assert qb.batch_minor(8, 16, batch, dp.itemsize)
+    X = jax.ShapeDtypeStruct((8, 16, batch), jnp.int32, sharding=one_chip)
+    fn = _flat(_tables(schedule), dp, batch_minor=True)
+    text = jax.jit(fn).lower(*[X] * (2 if datapath == "lanes" else 1)
+                             ).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert f"givens_qr_{dp.name}_batch_minor" in text
 
 
 @pytest.mark.parametrize("m,e,batch", [(64, 128, 64),    # 64x64 panel, Q
@@ -77,6 +102,7 @@ def test_blockfp_panel_kernels(one_chip, m, e, batch):
     """The first panel's factor (control words exported) and its replay
     over the trailing panels — the two kernels every panel sweep runs."""
     pw = qb.TILE_B
+    assert not qb.batch_minor(m, e, batch, qb.BlockFPPath.itemsize)
     piv, tgt, col = ops.panel_steps(m, pw)
     dp = qb.BlockFPPath(24, True)
     S = piv.shape[0]
